@@ -17,7 +17,8 @@ a merged pow2 bucket can overshoot the pair). Measures, per engine:
     launches inside jit) — fused MUST be exactly 1, separate exactly 2,
   * greedy-token parity between the two engines (bf16: bit-identical),
   * int8 KV residency from REAL array bytes: resident requests at equal
-    pool bytes must be >= 1.8x bf16 (the (Dh+4)/(2·Dh) layout bound).
+    pool bytes must be >= 1.8x bf16 (the (Dp+4)/(2·Dp) layout bound,
+    Dp = the lane-padded row width).
 
 Emits BENCH_fused_attention.json at the repo root. Asserted acceptance:
 fused mixed-step time strictly below the two-launch baseline, exactly one
@@ -116,7 +117,7 @@ def residency(model, block_size=16, num_blocks=64):
     how many int8 blocks fit in one full-precision pool's footprint.
     The asserted ``resident_ratio_vs_bf16`` normalizes the full pool to
     bf16 width (the reduced CPU model keeps f32 pools, which would
-    overstate the win) — the layout bound is 2·Dh/(Dh+4)."""
+    overstate the win) — the layout bound is 2·Dp/(Dp+4)."""
     full = model.init_paged_cache(num_blocks, block_size)
     int8 = model.init_paged_cache(num_blocks, block_size, kv_dtype="int8")
     b_full, b_int8 = kv_bytes(full), kv_bytes(int8)

@@ -99,9 +99,10 @@ def merge_kv_batch(cache, piece, index: int):
 def gather_kv_blocks(pool, block_ids):
     """Extract a request's physical blocks from a paged pool.
 
-    Pool leaves are [L, NB, BS, ...]; ``block_ids`` is the request's block
-    table (ordered logical->physical). Returns leaves [L, nb, BS, ...] —
-    the migration wire format for the paged engine (DESIGN.md §Migration):
+    Pool leaves are [L, NB, ...] (DESIGN.md §Block pool layout);
+    ``block_ids`` is the request's block table (ordered logical->physical).
+    Returns leaves [L, nb, ...] in pool layout — the engine turns them into
+    the contiguous wire format (``models.attention.blocks_to_piece``), so
     bytes moved scale with ceil(length/BS)·BS, not max_seq.
     """
     idx = jnp.asarray(block_ids, jnp.int32)
@@ -109,7 +110,7 @@ def gather_kv_blocks(pool, block_ids):
 
 
 def scatter_kv_blocks(pool, piece, block_ids):
-    """Write a gathered piece (leaves [L, nb, BS, ...]) into freshly
+    """Write pool-layout blocks (leaves [L, nb, ...]) into freshly
     allocated blocks of the destination pool."""
     idx = jnp.asarray(block_ids, jnp.int32)
 

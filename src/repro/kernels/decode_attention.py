@@ -15,7 +15,7 @@ Two layouts, three modes, same numerics:
     (SMEM) and fully-masked blocks skip the MXU work via ``pl.when`` —
     cost ∝ Σ_b ceil(L_b/BS) plus a small per-skipped-block grid overhead.
   * ``paged_decode_attention``: same ragged skip, but KV lives in a global
-    block *pool* ``[NB, BS, Hkv, Dh]`` and each request's blocks are chased
+    block *pool* ``[NB, Hkv, BS, Dp]`` and each request's blocks are chased
     through a prefetched block table — the serving engine's layout
     (DESIGN.md §Block pool), no per-request padding or copies at all.
 
@@ -63,8 +63,8 @@ def _decode_kernel(lengths_ref,          # scalar prefetch [B]
     start = j * block_s
 
     def _compute():
-        _flash_block_update(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                            start, length)
+        _flash_block_update(q_ref[0, 0], k_ref[0, :, 0], v_ref[0, :, 0],
+                            m_ref, l_ref, acc_ref, start, length)
 
     if ragged:
         # skip the MXU work for blocks entirely beyond this request's length
@@ -75,10 +75,20 @@ def _decode_kernel(lengths_ref,          # scalar prefetch [B]
     pl.when(j == nj - 1)(lambda: _flash_finish(o_ref, l_ref, acc_ref))
 
 
+def _paged_block_update(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, start,
+                        length):
+    """Flash step of a [G, Dh] decode q tile against one head-major pool
+    block tile [1, 1, BS, Dp] (lane padding past Dh is never read)."""
+    dh = q_ref.shape[-1]
+    _flash_block_update(q_ref[0, 0], k_ref[0, 0, :, 0:dh],
+                        v_ref[0, 0, :, 0:dh], m_ref, l_ref, acc_ref, start,
+                        length)
+
+
 def _paged_decode_kernel(lengths_ref,        # scalar prefetch [B]
                          bt_ref,             # scalar prefetch [B, NBT]
                          q_ref,              # [1, 1, G, Dh]
-                         k_ref, v_ref,       # [1, BS, 1, Dh] (one phys block)
+                         k_ref, v_ref,       # [1, 1, BS, Dp] (one phys block)
                          o_ref,              # [1, 1, G, Dh]
                          m_ref, l_ref, acc_ref,  # VMEM scratch
                          *, block_s: int):
@@ -98,7 +108,7 @@ def _paged_decode_kernel(lengths_ref,        # scalar prefetch [B]
     length = lengths_ref[b]
     start = j * block_s
     pl.when(start < length)(
-        lambda: _flash_block_update(q_ref, k_ref, v_ref, m_ref, l_ref,
+        lambda: _paged_block_update(q_ref, k_ref, v_ref, m_ref, l_ref,
                                     acc_ref, start, length))
     pl.when(j == nj - 1)(lambda: _flash_finish(o_ref, l_ref, acc_ref))
 
@@ -107,7 +117,7 @@ def _flat_paged_kernel(wreq_ref, wblk_ref,   # scalar prefetch [W], [W]
                        lengths_ref,          # scalar prefetch [B]
                        bt_ref,               # scalar prefetch [B, NBT]
                        q_ref,                # [1, 1, G, Dh]
-                       k_ref, v_ref,         # [1, BS, 1, Dh] (one phys block)
+                       k_ref, v_ref,         # [1, 1, BS, Dp] (one phys block)
                        o_ref,                # [1, 1, G, Dh]
                        m_ref, l_ref, acc_ref,  # VMEM scratch
                        *, block_s: int):
@@ -138,7 +148,7 @@ def _flat_paged_kernel(wreq_ref, wblk_ref,   # scalar prefetch [W], [W]
     length = lengths_ref[b]
     start = j * block_s
     pl.when(start < length)(
-        lambda: _flash_block_update(q_ref, k_ref, v_ref, m_ref, l_ref,
+        lambda: _paged_block_update(q_ref, k_ref, v_ref, m_ref, l_ref,
                                     acc_ref, start, length))
     pl.when(last)(lambda: _flash_finish(o_ref, l_ref, acc_ref))
 
@@ -159,7 +169,7 @@ def paged_decode_attention_flat(q, k_pool, v_pool, block_tables, lengths, *,
     just at the MXU level (DESIGN.md §Decode hot path).
     """
     B, H, Dh = q.shape
-    NB, BS, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    Hkv, BS, Dp = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
     NBT = block_tables.shape[1]
     assert H % Hkv == 0, (H, Hkv)
@@ -180,7 +190,7 @@ def paged_decode_attention_flat(q, k_pool, v_pool, block_tables, lengths, *,
         del lens
         # padding items carry block index NBT; clamp for the table lookup —
         # whatever block it DMAs is skipped by the kernel's length guard
-        return (bt[wreq[w], jnp.minimum(wblk[w], NBT - 1)], 0, h, 0)
+        return (bt[wreq[w], jnp.minimum(wblk[w], NBT - 1)], h, 0, 0)
 
     out = pl.pallas_call(
         kernel,
@@ -189,8 +199,8 @@ def paged_decode_attention_flat(q, k_pool, v_pool, block_tables, lengths, *,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, Dh), q_map),
-                pl.BlockSpec((1, BS, 1, Dh), kv_map),
-                pl.BlockSpec((1, BS, 1, Dh), kv_map),
+                pl.BlockSpec((1, 1, BS, Dp), kv_map),
+                pl.BlockSpec((1, 1, BS, Dp), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, G, Dh), q_map),
             scratch_shapes=[
@@ -200,6 +210,7 @@ def paged_decode_attention_flat(q, k_pool, v_pool, block_tables, lengths, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
+        name="paged_decode_attention_flat",
         interpret=interpret,
     )(work_req, work_blk, lengths, block_tables, qg, k_pool, v_pool)
     return out.reshape(B, H, Dh)
@@ -211,7 +222,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     """Decode attention over a paged KV pool.
 
     q            [B, H, Dh]              — one query token per request
-    k/v_pool     [NB, BS, Hkv, Dh]       — global physical block pool
+    k/v_pool     [NB, Hkv, BS, Dp]       — global physical block pool
+                                           (head-major, rows lane-padded
+                                           to Dp >= Dh)
     block_tables [B, NBT] int32          — physical block id per logical
                                            block; rows past a request's
                                            ceil(L_b/BS) blocks are padding
@@ -226,7 +239,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     padded to another's length, so the grid cost is Σ_b ceil(L_b/BS).
     """
     B, H, Dh = q.shape
-    NB, BS, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    Hkv, BS, Dp = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
     NBT = block_tables.shape[1]
     assert H % Hkv == 0, (H, Hkv)
@@ -237,7 +250,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
 
     def kv_map(b, h, j, lens, bt):
         del lens
-        return (bt[b, j], 0, h, 0)
+        return (bt[b, j], h, 0, 0)
 
     out = pl.pallas_call(
         kernel,
@@ -246,8 +259,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, *pf: (b, h, 0, 0)),
-                pl.BlockSpec((1, BS, 1, Dh), kv_map),
-                pl.BlockSpec((1, BS, 1, Dh), kv_map),
+                pl.BlockSpec((1, 1, BS, Dp), kv_map),
+                pl.BlockSpec((1, 1, BS, Dp), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, G, Dh),
                                    lambda b, h, j, *pf: (b, h, 0, 0)),
@@ -258,6 +271,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
+        name="paged_decode_attention",
         interpret=interpret,
     )(lengths, block_tables, qg, k_pool, v_pool)
     return out.reshape(B, H, Dh)

@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels, plus the ONE shared
 flash-attention inner core every paged kernel builds on.
 
-On this CPU container the kernels run in ``interpret=True`` (Python
-execution of the kernel body) — numerics are identical to TPU. The
+Off a TPU the kernels run with ``interpret=True`` (Python execution of
+the kernel body); on a TPU they always compile. The
 ``backend`` argument lets callers (engine, tests) pick:
 
   * ``"xla"``     — pure-jnp reference (fast on CPU, default here)
@@ -25,38 +25,31 @@ NEG_INF = -1e30
 # --------------------------------------------------------------------------
 # Shared flash-attention core (decode / chunked prefill / fused mixed)
 # --------------------------------------------------------------------------
-def _flash_block_update(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                        start, length, qpos=None, k_scale=None, v_scale=None,
-                        rows=None):
+def _flash_block_update(q, k, v, m_ref, l_ref, acc_ref, start, length,
+                        qpos=None, k_scale=None, v_scale=None):
     """ONE online-softmax KV-block step, shared by the decode kernels, the
     chunked-prefill kernel AND the fused mixed-iteration kernel: the q
-    tile (trailing dims flattened to [rows, Dh] — [G, Dh] for decode,
-    [C·G, Dh] for a prefill chunk) vs. this grid step's KV block
-    [BS, Dh], masked at ``length``, accumulated into the persistent
-    (m, l, acc) scratch.
+    rows ``q`` [rows, Dh] ([G, Dh] for a decode row, [C·G, Dh] for a
+    prefill chunk) vs. this grid step's KV block ``k``/``v`` [BS, Dh],
+    masked at ``length``, accumulated into the FIRST ``rows`` rows of the
+    persistent (m, l, acc) scratch. Callers load the tiles from their
+    refs, so the core is independent of the operand layouts.
 
     ``qpos`` (per-row global query positions) additionally applies the
     causal ``kv <= q`` mask of chunked prefill; decode's single query row
-    needs none. ``k_scale``/``v_scale`` ([BS, 1], f32) dequantize an int8
-    KV block in-register — the pool stays int8 in HBM, so DMA bytes halve
-    (DESIGN.md §Quantized KV blocks). ``rows`` (static) restricts the
-    update to the FIRST ``rows`` scratch rows reading the q tile's first
-    chunk row only — the fused kernel's tagged decode items use it to pay
-    a [G, BS] matmul instead of the chunk tile's [C·G, BS]."""
-    if rows is None:
-        q = q_ref[0, 0].astype(jnp.float32).reshape(-1, q_ref.shape[-1])
-        sl = slice(None)
-    else:
-        # tagged decode item inside a chunk-shaped tile: first chunk row
-        q = q_ref[0, 0, 0].astype(jnp.float32).reshape(rows,
-                                                       q_ref.shape[-1])
-        sl = slice(0, rows)
-    k = k_ref[0, :, 0].astype(jnp.float32)          # [BS, Dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    if k_scale is not None:
-        k = k * k_scale                             # [BS, 1] row scales
-        v = v * v_scale
+    needs none. ``k_scale``/``v_scale`` ([1, BS], f32, one per KV row)
+    dequantize an int8 KV block: they scale the score columns and the
+    probability columns, which equals scaling the K/V rows, so the pool
+    stays int8 in HBM and DMA bytes halve (DESIGN.md §Quantized KV
+    blocks)."""
+    rows = q.shape[0]
+    sl = slice(0, rows)
+    q = q.astype(jnp.float32)
+    k = k.astype(jnp.float32)                       # [BS, Dh]
+    v = v.astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [rows, BS]
+    if k_scale is not None:
+        s = s * k_scale
     s = s / math.sqrt(q.shape[-1])
     idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     keep = idx < length
@@ -64,17 +57,16 @@ def _flash_block_update(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
         keep &= idx <= qpos
     s = jnp.where(keep, s, NEG_INF)
 
-    m_prev = m_ref[sl, 0]                           # [rows]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_prev = m_ref[sl, 0:1]                         # [rows, 1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])                 # [rows, BS]
-    l_new = l_ref[sl, 0] * alpha + p.sum(axis=-1)
-    acc_ref[sl, :] = (acc_ref[sl, :] * alpha[:, None]
-                      + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
-    m_ref[sl, :] = jnp.broadcast_to(m_new[:, None], (q.shape[0],
-                                                     m_ref.shape[1]))
-    l_ref[sl, :] = jnp.broadcast_to(l_new[:, None], (q.shape[0],
-                                                     l_ref.shape[1]))
+    p = jnp.exp(s - m_new)                          # [rows, BS]
+    l_new = l_ref[sl, 0:1] * alpha + p.sum(axis=-1, keepdims=True)
+    pv = p if v_scale is None else p * v_scale
+    acc_ref[sl, :] = (acc_ref[sl, :] * alpha
+                      + jax.lax.dot_general(pv, v, (((1,), (0,)), ((), ()))))
+    m_ref[sl, :] = jnp.broadcast_to(m_new, (rows, m_ref.shape[1]))
+    l_ref[sl, :] = jnp.broadcast_to(l_new, (rows, l_ref.shape[1]))
 
 
 def _flash_init(m_ref, l_ref, acc_ref):
@@ -84,10 +76,11 @@ def _flash_init(m_ref, l_ref, acc_ref):
 
 
 def _flash_finish(o_ref, l_ref, acc_ref):
-    l = l_ref[:, 0]
+    """Normalize the accumulators into the [rows, Dh] output tile
+    ``o_ref[0, 0]``."""
+    l = l_ref[:, 0:1]
     safe = jnp.where(l == 0.0, 1.0, l)
-    out = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
-    o_ref[0, 0] = out.reshape(o_ref.shape[2:])   # [G,Dh] / prefill [C,G,Dh]
+    o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def flat_work_list(lengths, nbt: int, block_s: int, num_work: int):

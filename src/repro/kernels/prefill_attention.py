@@ -155,11 +155,11 @@ def prefill_attention(q, k, v, lengths=None, *, block_q: int = 256,
 def _paged_prefill_kernel(wreq_ref, wblk_ref,    # scalar prefetch [W], [W]
                           ctx_ref, clen_ref,     # scalar prefetch [B], [B]
                           bt_ref,                # scalar prefetch [B, NBT]
-                          q_ref,                 # [1, 1, C, G, Dh]
-                          k_ref, v_ref,          # [1, BS, 1, Dh] (one block)
-                          o_ref,                 # [1, 1, C, G, Dh]
+                          q_ref,                 # [1, 1, C·G, Dh]
+                          k_ref, v_ref,          # [1, 1, BS, Dp] (one block)
+                          o_ref,                 # [1, 1, C·G, Dh]
                           m_ref, l_ref, acc_ref,   # VMEM scratch
-                          *, block_s: int):
+                          *, block_s: int, group: int):
     """Flat-work-list chunked prefill: grid step (h, w) processes work item
     ``w`` = (chunk ``wreq[w]``, logical KV block ``wblk[w]``) — the C
     queries of that chunk against ONE physical pool block holding logical
@@ -187,12 +187,12 @@ def _paged_prefill_kernel(wreq_ref, wblk_ref,    # scalar prefetch [W], [W]
     start = j * block_s
 
     def _compute():
-        G = q_ref.shape[3]
-        rows = q_ref.shape[2] * G                           # C·G
+        rows, dh = q_ref.shape[2], q_ref.shape[3]           # C·G, Dh
         # per-row global query position (row r is chunk token r // G),
         # kept 2-d ([rows, 1], broadcastable) — TPU iota must be >= 2-d
-        qpos = ctx + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // G
-        _flash_block_update(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+        qpos = ctx + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // group
+        _flash_block_update(q_ref[0, 0], k_ref[0, 0, :, 0:dh],
+                            v_ref[0, 0, :, 0:dh], m_ref, l_ref, acc_ref,
                             start, total, qpos=qpos)
 
     pl.when(start < total)(_compute)
@@ -209,7 +209,8 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                                         (rows past ``chunk_lens[b]`` are
                                         padding; their output is garbage
                                         and must be ignored by the caller)
-    k/v_pool     [NB, BS, Hkv, Dh]    — global block pool. The chunk's own
+    k/v_pool     [NB, Hkv, BS, Dp]    — global block pool (head-major, rows
+                                        lane-padded to Dp >= Dh). The chunk's own
                                         K/V must ALREADY be scattered into
                                         its blocks (positions ctx..ctx+C)
                                         before this call — partial prompts
@@ -229,28 +230,29 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     two; None = the worst case B·NBT).
     """
     B, C, H, Dh = q.shape
-    BS, Hkv = k_pool.shape[1], k_pool.shape[2]
+    Hkv, BS, Dp = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
     NBT = block_tables.shape[1]
     assert H % Hkv == 0, (H, Hkv)
     W = num_work if num_work is not None else B * NBT
     assert W >= 1
-    qg = q.reshape(B, C, Hkv, G, Dh).transpose(0, 2, 1, 3, 4)
+    qg = q.reshape(B, C, Hkv, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, C * G, Dh)
     totals = (ctx_lens + chunk_lens).astype(jnp.int32)
     work_req, work_blk = flat_work_list(totals, NBT, BS, W)
 
     grid = (Hkv, W)
-    kernel = functools.partial(_paged_prefill_kernel, block_s=BS)
+    kernel = functools.partial(_paged_prefill_kernel, block_s=BS, group=G)
 
     def q_map(h, w, wreq, wblk, ctx, clen, bt):
         del wblk, ctx, clen, bt
-        return (wreq[w], h, 0, 0, 0)
+        return (wreq[w], h, 0, 0)
 
     def kv_map(h, w, wreq, wblk, ctx, clen, bt):
         del ctx, clen
         # padding items carry block index NBT; clamp for the table lookup —
         # whatever block it DMAs is skipped by the kernel's total guard
-        return (bt[wreq[w], jnp.minimum(wblk[w], NBT - 1)], 0, h, 0)
+        return (bt[wreq[w], jnp.minimum(wblk[w], NBT - 1)], h, 0, 0)
 
     out = pl.pallas_call(
         kernel,
@@ -258,19 +260,21 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, ctx_lens,
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, C, G, Dh), q_map),
-                pl.BlockSpec((1, BS, 1, Dh), kv_map),
-                pl.BlockSpec((1, BS, 1, Dh), kv_map),
+                pl.BlockSpec((1, 1, C * G, Dh), q_map),
+                pl.BlockSpec((1, 1, BS, Dp), kv_map),
+                pl.BlockSpec((1, 1, BS, Dp), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, C, G, Dh), q_map),
+            out_specs=pl.BlockSpec((1, 1, C * G, Dh), q_map),
             scratch_shapes=[
                 pltpu.VMEM((C * G, 128), jnp.float32),   # m (lane-replicated)
                 pltpu.VMEM((C * G, 128), jnp.float32),   # l
                 pltpu.VMEM((C * G, Dh), jnp.float32),    # acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, C, G, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, C * G, Dh), q.dtype),
+        name="paged_prefill_attention",
         interpret=interpret,
     )(work_req, work_blk, ctx_lens.astype(jnp.int32),
       chunk_lens.astype(jnp.int32), block_tables, qg, k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, C, H, Dh)
+    return out.reshape(B, Hkv, C, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, C, H, Dh)

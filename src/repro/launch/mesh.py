@@ -9,10 +9,8 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kwarg(n: int) -> dict:
-    # jax.sharding.AxisType landed after 0.4.x; Auto is that era's default
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -20,15 +18,15 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwarg(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Tiny mesh over the locally available devices (tests)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh(
-        (n // model, model), ("data", "model"), **_axis_types_kwarg(2))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def make_tp_mesh(tp: int) -> jax.sharding.Mesh:
@@ -38,7 +36,7 @@ def make_tp_mesh(tp: int) -> jax.sharding.Mesh:
     is a set of disjoint meshes over one host's devices."""
     n = len(jax.devices())
     assert 1 <= tp <= n, f"tp={tp} needs {tp} devices, have {n}"
-    return jax.make_mesh((tp,), ("model",), **_axis_types_kwarg(1),
+    return jax.make_mesh((tp,), ("model",), axis_types=_auto(1),
                          devices=jax.devices()[:tp])
 
 

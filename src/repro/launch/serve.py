@@ -11,18 +11,39 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+from pathlib import Path
+from typing import Any, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.configs import get_config
 from repro.core.partition import PipelinePlan, Stage
 from repro.core.qoe import QoEModel
+from repro.launch.mesh import make_tp_mesh
+from repro.launch.shardings import serving_param_spec_tree
 from repro.models import build_model
+from repro.models.common import ModelConfig
 from repro.sched import assign_classes, parse_class_mix
 from repro.serving.server import (MILSServer, ServerConfig,
                                   requests_from_trace)
 from repro.sim.workload import WorkloadSpec, generate
+
+# fixed in-checkout home of the persistent compilation cache (listed in
+# .gitignore); a fixed path matters, since it is part of the cache key
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache — the one place the repo
+    sets it. ``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the
+    cache lives in :data:`COMPILE_CACHE_DIR`. Returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        COMPILE_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def default_plan(num_engines: int, max_seq: int) -> PipelinePlan:
@@ -34,6 +55,40 @@ def default_plan(num_engines: int, max_seq: int) -> PipelinePlan:
     return PipelinePlan(
         [Stage(0.0, max_seq / 4, num_engines - half),
          Stage(max_seq / 4, float("inf"), half)], 0.0)
+
+
+def init_params(model, seed: int = 0, tp: int = 1):
+    """Random weights from ``seed``. With ``tp > 1`` every weight is
+    created straight into its serving sharding over :func:`make_tp_mesh`
+    (DESIGN.md §Sharded serving), so a model that needs several chips is
+    never whole on one of them."""
+    key = jax.random.PRNGKey(seed)
+    if tp == 1:
+        return jax.jit(model.init)(key)
+    mesh = make_tp_mesh(tp)
+    specs = serving_param_spec_tree(jax.eval_shape(model.init, key), tp)
+    return jax.jit(model.init, out_shardings=jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs))(key)
+
+
+def build_server(cfg: ModelConfig, server_cfg: ServerConfig, *,
+                 engines: int = 4, tp: Any = 1,
+                 max_seq: int = 128, max_slots: int = 3,
+                 params: Optional[Any] = None, **engine_kwargs) -> MILSServer:
+    """A MILS cluster of ``engines`` engines serving ``cfg`` — any width,
+    from a ``reduced()`` test config to a published one — behind the
+    bootstrapping :func:`default_plan`. Weights are random from seed 0
+    unless ``params`` is given; a uniform ``tp > 1`` initialises them
+    sharded (:func:`init_params`). ``engine_kwargs`` go to every
+    :class:`~repro.serving.engine.Engine`."""
+    model = build_model(cfg)
+    tps = list(tp) if isinstance(tp, (list, tuple)) else [int(tp)] * engines
+    if params is None:
+        params = init_params(model, 0, tps[0] if len(set(tps)) == 1 else 1)
+    qoe = QoEModel(np.array([1e-3, 1e-4, 1e-6, 0.0, 1e-6]))
+    return MILSServer(model, params, default_plan(engines, max_seq), qoe,
+                      server_cfg, tp=tp, max_slots=max_slots,
+                      max_seq=max_seq, **engine_kwargs)
 
 
 def main() -> None:
@@ -165,34 +220,25 @@ def main() -> None:
             f"{len(jax.devices())} (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} for CPU)")
 
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced()
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    plan = default_plan(args.engines, args.max_seq)
-    qoe = QoEModel(np.array([1e-3, 1e-4, 1e-6, 0.0, 1e-6]))
-    srv = MILSServer(model, params, plan, qoe,
-                     ServerConfig(policy=args.policy,
-                                  refinement=args.refinement,
-                                  balancing=args.balancing, seed=args.seed,
-                                  preemption=args.preemption,
-                                  slo_scale=args.slo_scale,
-                                  slo_time_scale=args.slo_time_scale,
-                                  faults=faults,
-                                  migration_timeout_steps=
-                                  args.migration_timeout_steps,
-                                  dead_after_steps=args.dead_after_steps,
-                                  host_kv_budget=(args.host_kv_budget
-                                                  if args.prefix_cache
-                                                  else 0)),
-                     tp=tp,
-                     max_slots=args.max_slots, max_seq=args.max_seq,
-                     attn_backend=args.attn_backend,
-                     kv_dtype=args.kv_dtype,
-                     device_resident=False if args.host_loop else None,
-                     prefill_token_budget=args.prefill_budget,
-                     chunked_prefill=(False if args.no_chunked_prefill
-                                      else None),
-                     prefix_cache=args.prefix_cache)
+    srv = build_server(
+        cfg,
+        ServerConfig(policy=args.policy, refinement=args.refinement,
+                     balancing=args.balancing, seed=args.seed,
+                     preemption=args.preemption, slo_scale=args.slo_scale,
+                     slo_time_scale=args.slo_time_scale, faults=faults,
+                     migration_timeout_steps=args.migration_timeout_steps,
+                     dead_after_steps=args.dead_after_steps,
+                     host_kv_budget=(args.host_kv_budget
+                                     if args.prefix_cache else 0)),
+        engines=args.engines, tp=tp, max_slots=args.max_slots,
+        max_seq=args.max_seq, attn_backend=args.attn_backend,
+        kv_dtype=args.kv_dtype,
+        device_resident=False if args.host_loop else None,
+        prefill_token_budget=args.prefill_budget,
+        chunked_prefill=False if args.no_chunked_prefill else None,
+        prefix_cache=args.prefix_cache)
     # the same ShareGPT-shaped trace the simulator runs, arrival times
     # mapped to server steps, lengths capped to the reduced model
     spec = WorkloadSpec(rate=args.arrival_rate,

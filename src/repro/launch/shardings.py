@@ -247,16 +247,27 @@ def serving_param_spec_tree(params, tp: int) -> Any:
     return jax.tree_util.tree_unflatten(tdef, specs)
 
 
-def pool_spec_tree(pool) -> Any:
-    """PartitionSpec pytree for a paged KV pool (or a contiguous KV
-    piece): the kv-head axis — dim 3 of [L, NB, BS, Hkv, Dh] rows and of
-    [L, NB, BS, Hkv] int8 scales — shards on 'model'; block ids, work
-    lists and every other axis stay replicated, so the allocator, prefix
-    index and migration bookkeeping never see the mesh."""
+def _kv_head_specs(tree, axis: int) -> Any:
     def one(leaf):
         nd = len(leaf.shape)
-        assert nd >= 4, f"pool leaf rank {nd} < 4"
+        assert nd >= 4, f"kv leaf rank {nd} < 4"
         spec = [None] * nd
-        spec[3] = "model"
+        spec[axis] = "model"
         return P(*spec)
-    return jax.tree.map(one, pool)
+    return jax.tree.map(one, tree)
+
+
+def pool_spec_tree(pool) -> Any:
+    """PartitionSpec pytree for a paged KV pool: the kv-head axis — dim 2
+    of [L, NB, Hkv, BS, Dp] rows and of [L, NB, Hkv, BS] int8 scales —
+    shards on 'model'; block ids, work lists and every other axis stay
+    replicated, so the allocator, prefix index and migration bookkeeping
+    never see the mesh."""
+    return _kv_head_specs(pool, 2)
+
+
+def piece_spec_tree(piece) -> Any:
+    """PartitionSpec pytree for a contiguous KV piece (the migration wire
+    format, [L, 1, T, Hkv, Dh] rows and [L, 1, T, Hkv] scales): dim 3,
+    the kv-head axis, shards on 'model'."""
+    return _kv_head_specs(piece, 3)

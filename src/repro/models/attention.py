@@ -11,7 +11,6 @@ this is what ``serve_step`` lowers in the multi-pod dry-run.
 from __future__ import annotations
 
 import math
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -35,19 +34,32 @@ PAGED_BACKENDS = ("dense", "grid", "flat", "fused")
 
 # KV block-pool storage layouts (DESIGN.md §Quantized KV blocks):
 #   bf16 — the model dtype, full-width rows.
-#   int8 — symmetric per-(block, position, kv-head) int8 with f32 row
+#   int8 — symmetric per-(block, kv-head, position) int8 with f32 row
 #          scales; quantize-on-write, dequant in-register inside the
 #          flash core. Supported by the "fused" and "dense" backends.
 KV_DTYPES = ("bf16", "int8")
 
+# TPU vector lanes. Pool rows are padded to a multiple of this so the TPU
+# keeps the pool row-major: with a narrower minor dim its default layout
+# moves the block axis into the lanes, and every kernel call would then
+# copy the whole pool into the layout the kernel reads (DESIGN.md §Block
+# pool layout).
+LANES = 128
+
+
+def pool_row_width(head_dim: int) -> int:
+    """Lane width ``Dp`` of one pool row: ``head_dim`` rounded up to
+    :data:`LANES`; lanes past ``head_dim`` hold zeros and are never read."""
+    return -(-head_dim // LANES) * LANES
+
 
 def resolve_paged_backend(backend: Optional[str] = None):
-    """(backend, interpret) for this process. Explicit arg wins, then the
-    REPRO_PAGED_ATTN env var, then auto: the fused Pallas kernel on TPU,
-    the dense XLA path elsewhere (Pallas off-TPU would need interpret
-    mode, which is for validation, not speed). Asking for a kernel
-    backend off-TPU gets interpret=True so it still runs."""
-    choice = backend or os.environ.get("REPRO_PAGED_ATTN", "auto")
+    """(backend, interpret) for this process. An explicit backend wins,
+    else auto: the fused Pallas kernel on TPU, the dense XLA path
+    elsewhere (Pallas off-TPU would need interpret mode, which is for
+    validation, not speed). Asking for a kernel backend off-TPU gets
+    interpret=True so it still runs; on TPU a kernel is always compiled."""
+    choice = backend or "auto"
     on_tpu = jax.default_backend() == "tpu"
     if choice == "auto":
         choice = "fused" if on_tpu else "dense"
@@ -279,13 +291,15 @@ class KVCache(NamedTuple):
 
 class QuantKVCache(NamedTuple):
     """int8 paged block pool (DESIGN.md §Quantized KV blocks): K/V rows are
-    symmetric int8 over the head dim with f32 per-(block, position,
-    kv-head) scales — (Dh + 4)/(2·Dh) of the bf16 bytes, ≈ 1.94× resident
-    requests at Dh = 128. A pytree like :class:`KVCache`, so the generic
-    block gather/scatter/migration helpers work unchanged."""
-    k: jnp.ndarray        # [NB, BS, Hkv, Dh] int8
+    symmetric int8 over the head dim with f32 per-(block, kv-head,
+    position) scales — (Dp + 4)/(2·Dp) of the bf16 bytes, ≈ 1.94×
+    resident requests. A pytree like :class:`KVCache`, so the generic
+    block gather/scatter/migration helpers work unchanged. Contiguous
+    pieces (migration wire format) use the token-major twin: K/V
+    ``[..., T, Hkv, Dh]``, scales ``[..., T, Hkv]``."""
+    k: jnp.ndarray        # [NB, Hkv, BS, Dp] int8
     v: jnp.ndarray
-    k_scale: jnp.ndarray  # [NB, BS, Hkv] f32
+    k_scale: jnp.ndarray  # [NB, Hkv, BS] f32
     v_scale: jnp.ndarray
 
 
@@ -300,20 +314,35 @@ def quantize_kv(x):
     return q, scale
 
 
+def _pad_lanes(x, width: int):
+    """Zero-pad the last (head) dim of ``x`` up to ``width`` lanes."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def scatter_pool(pool_l, blk, off, k, v):
     """Write new K/V rows into one layer's pool slice at physical
-    ``(blk, off)`` — quantize-on-write when the pool is int8. ``blk``/
-    ``off`` are int32 of any matching shape S; ``k``/``v`` are [*S, Hkv,
-    Dh] in compute dtype."""
+    ``(blk, h, off)`` for every kv head h — quantize-on-write when the
+    pool is int8. ``blk``/``off`` are int32 of any matching shape S;
+    ``k``/``v`` are [*S, Hkv, Dh] in compute dtype. Each update is one
+    [Dp] row addressed by a full (block, head, position) index, so the
+    scatter works on the pool's own row-major layout (a head-window
+    scatter would make XLA relayout the pool around it)."""
+    dp = pool_l.k.shape[-1]
+    heads = jnp.arange(pool_l.k.shape[1], dtype=jnp.int32)
+    idx = (blk[..., None], heads, off[..., None])        # each [*S, Hkv]
     if isinstance(pool_l, QuantKVCache):
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
-        return QuantKVCache(pool_l.k.at[blk, off].set(kq),
-                            pool_l.v.at[blk, off].set(vq),
-                            pool_l.k_scale.at[blk, off].set(ks),
-                            pool_l.v_scale.at[blk, off].set(vs))
-    return KVCache(pool_l.k.at[blk, off].set(k.astype(pool_l.k.dtype)),
-                   pool_l.v.at[blk, off].set(v.astype(pool_l.v.dtype)))
+        return QuantKVCache(pool_l.k.at[idx].set(_pad_lanes(kq, dp)),
+                            pool_l.v.at[idx].set(_pad_lanes(vq, dp)),
+                            pool_l.k_scale.at[idx].set(ks),
+                            pool_l.v_scale.at[idx].set(vs))
+    return KVCache(
+        pool_l.k.at[idx].set(_pad_lanes(k, dp).astype(pool_l.k.dtype)),
+        pool_l.v.at[idx].set(_pad_lanes(v, dp).astype(pool_l.v.dtype)))
 
 
 def _pool_scales(pool_l):
@@ -323,17 +352,44 @@ def _pool_scales(pool_l):
     return None, None
 
 
-def _gather_dequant(pool_l, block_tables):
+def _gather_dequant(pool_l, block_tables, head_dim: int):
     """Dense-path gather of a request-contiguous [B, NBT·BS, Hkv, Dh]
     view, dequantized to f32 when the pool is int8."""
-    k_seq = paged_gather(pool_l.k, block_tables)
-    v_seq = paged_gather(pool_l.v, block_tables)
+    k_seq = paged_gather(pool_l.k, block_tables)[..., :head_dim]
+    v_seq = paged_gather(pool_l.v, block_tables)[..., :head_dim]
     if isinstance(pool_l, QuantKVCache):
         ks = paged_gather(pool_l.k_scale, block_tables)   # [B, S, Hkv]
         vs = paged_gather(pool_l.v_scale, block_tables)
         k_seq = k_seq.astype(jnp.float32) * ks[..., None]
         v_seq = v_seq.astype(jnp.float32) * vs[..., None]
     return k_seq, v_seq
+
+
+def blocks_to_piece(blocks, head_dim: int):
+    """Gathered pool blocks (leaves [L, nb, Hkv, BS, Dp], int8 scales
+    [L, nb, Hkv, BS]) -> the contiguous token-major piece of the
+    migration wire format (leaves [L, 1, nb·BS, Hkv, Dh], scales
+    [L, 1, nb·BS, Hkv]); the lane padding of rank-5 row leaves is
+    dropped."""
+    def one(a):
+        a = jnp.swapaxes(a, 2, 3)
+        a = a.reshape(a.shape[0], 1, -1, *a.shape[3:])
+        return a[..., :head_dim] if a.ndim == 5 else a
+    return jax.tree.map(one, blocks)
+
+
+def piece_to_blocks(piece, nb: int, block_size: int, row_width: int):
+    """Inverse of :func:`blocks_to_piece`: a contiguous piece (leaves
+    [L, 1, T, Hkv, ...], T <= nb·BS) -> ``nb`` pool-layout blocks, the
+    tail zero-filled and rank-5 row leaves lane-padded to ``row_width``."""
+    def one(p):
+        pad = [(0, 0)] * p.ndim
+        pad[2] = (0, nb * block_size - p.shape[2])
+        p = jnp.pad(p, pad)[:, 0]
+        p = p.reshape(p.shape[0], nb, block_size, *p.shape[2:])
+        p = jnp.swapaxes(p, 2, 3)
+        return _pad_lanes(p, row_width) if p.ndim == 5 else p
+    return jax.tree.map(one, piece)
 
 
 def quantize_piece(piece):
@@ -418,13 +474,13 @@ def attention_decode(p, cfg: ModelConfig, x, cache: KVCache, pos,
 # Paged decode: one token vs. a global block pool + per-request block table.
 # --------------------------------------------------------------------------
 def paged_gather(pool, block_tables):
-    """pool [NB, BS, Hkv, Dh]; block_tables [B, NBT] int32 ->
-    contiguous per-request view [B, NBT*BS, Hkv, Dh]. Rows past a
+    """pool [NB, Hkv, BS, ...]; block_tables [B, NBT] int32 ->
+    contiguous per-request view [B, NBT*BS, Hkv, ...]. Rows past a
     request's length come from padding table entries and must be masked
     by the caller."""
     B, NBT = block_tables.shape
-    g = pool[block_tables]                       # [B, NBT, BS, Hkv, Dh]
-    return g.reshape(B, NBT * pool.shape[1], *pool.shape[2:])
+    g = jnp.swapaxes(pool[block_tables], 2, 3)   # [B, NBT, BS, Hkv, ...]
+    return g.reshape(B, NBT * pool.shape[2], *g.shape[3:])
 
 
 def attention_decode_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
@@ -434,7 +490,7 @@ def attention_decode_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
                            attn_num_work: Optional[int] = None):
     """Block-table variant of :func:`attention_decode`.
 
-    x [B, 1, D]; pool_l leaves [NB, BS, Hkv, Dh] — ONE layer's slice of the
+    x [B, 1, D]; pool_l leaves [NB, Hkv, BS, Dp] — ONE layer's slice of the
     engine's global block pool; block_tables [B, NBT] int32 physical block
     ids (padded rows arbitrary); pos [B] int32 tokens already cached
     (``pos = -1`` marks a dead batch slot: its write lands in the padding
@@ -468,7 +524,7 @@ def attention_decode_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
         k = apply_rope(k, pp, cfg.rope_theta)
 
     _check_kv_backend(pool_l, attn_backend)
-    BS = pool_l.k.shape[1]
+    BS = pool_l.k.shape[2]
     blk = jnp.take_along_axis(block_tables, (pos // BS)[:, None], axis=1)[:, 0]
     off = pos % BS
     new_pool = scatter_pool(pool_l, blk, off, k[:, 0], v[:, 0])
@@ -499,7 +555,7 @@ def attention_decode_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
                 interpret=attn_interpret)
         out = o[:, None].astype(q.dtype)         # [B, 1, H, Dh]
     else:
-        k_seq, v_seq = _gather_dequant(new_pool, block_tables)
+        k_seq, v_seq = _gather_dequant(new_pool, block_tables, cfg.head_dim)
         kpos = jnp.arange(k_seq.shape[1])[None, :]
         mask = (kpos <= pos[:, None])[:, None, None, None, :]
         out = _gqa_sdpa(q, k_seq, v_seq, mask)
@@ -514,7 +570,7 @@ def attention_prefill_chunk_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
     """Chunked prefill against the paged pool (DESIGN.md §Chunked prefill).
 
     x [B, C, D] — B prompt chunks of C tokens (rows past ``chunk_len``
-    are padding); pool_l leaves [NB, BS, Hkv, Dh] — ONE layer's slice of
+    are padding); pool_l leaves [NB, Hkv, BS, Dp] — ONE layer's slice of
     the global block pool; block_tables [B, NBT] int32 covering at least
     ``ceil((ctx_len + C)/BS)`` rows (the tail padded with a garbage
     block, so padding-row writes never touch live data); ctx_len [B] (or
@@ -547,7 +603,7 @@ def attention_prefill_chunk_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
         k = apply_rope(k, positions, cfg.rope_theta)
 
     _check_kv_backend(pool_l, attn_backend)
-    BS = pool_l.k.shape[1]
+    BS = pool_l.k.shape[2]
     blk = jnp.take_along_axis(block_tables, positions // BS, axis=1)  # [B, C]
     off = positions % BS
     # chunk positions are distinct per request and requests never share
@@ -571,7 +627,7 @@ def attention_prefill_chunk_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
                                       interpret=attn_interpret)
         out = out.astype(q.dtype)
     else:
-        k_seq, v_seq = _gather_dequant(new_pool, block_tables)
+        k_seq, v_seq = _gather_dequant(new_pool, block_tables, cfg.head_dim)
         kpos = jnp.arange(k_seq.shape[1])[None, None, :]        # [1, 1, S]
         mask = (kpos <= positions[:, :, None])[:, None, None]   # [B,1,1,C,S]
         out = _gqa_sdpa(q, k_seq, v_seq, mask)
@@ -619,7 +675,7 @@ def attention_mixed_paged(p, cfg: ModelConfig, x_dec, x_ck, pool_l,
         qc = apply_rope(qc, positions, cfg.rope_theta)
         kc = apply_rope(kc, positions, cfg.rope_theta)
 
-    BS = pool_l.k.shape[1]
+    BS = pool_l.k.shape[2]
     blk_d = jnp.take_along_axis(bt_dec, (pos // BS)[:, None], axis=1)[:, 0]
     blk_c = jnp.take_along_axis(bt_ck, positions // BS, axis=1)
     pool1 = scatter_pool(pool_l, blk_d, pos % BS, kd[:, 0], vd[:, 0])
@@ -649,11 +705,11 @@ def attention_mixed_paged(p, cfg: ModelConfig, x_dec, x_ck, pool_l,
     else:
         # dense bit-parity reference: the same two-gather SDPA halves the
         # separate-kernel path runs (CPU/debug fallback)
-        kd_seq, vd_seq = _gather_dequant(new_pool, bt_dec)
+        kd_seq, vd_seq = _gather_dequant(new_pool, bt_dec, cfg.head_dim)
         kpos = jnp.arange(kd_seq.shape[1])[None, :]
         mask = (kpos <= pos[:, None])[:, None, None, None, :]
         out_d = _gqa_sdpa(qd, kd_seq, vd_seq, mask)
-        kc_seq, vc_seq = _gather_dequant(new_pool, bt_ck)
+        kc_seq, vc_seq = _gather_dequant(new_pool, bt_ck, cfg.head_dim)
         kpos = jnp.arange(kc_seq.shape[1])[None, None, :]
         mask = (kpos <= positions[:, :, None])[:, None, None]
         out_c = _gqa_sdpa(qc, kc_seq, vc_seq, mask)
@@ -662,12 +718,17 @@ def attention_mixed_paged(p, cfg: ModelConfig, x_dec, x_ck, pool_l,
 
 
 def make_paged_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
-                    dtype=None, kv_dtype: str = "bf16"):
-    """Zeroed global block pool for ONE layer: [NB, BS, Hkv, Dh].
-    ``kv_dtype="int8"`` returns the quantized layout (zero scales, so
-    garbage blocks dequantize to exact zeros)."""
+                    dtype=None, kv_dtype: str = "bf16", layers=None):
+    """Zeroed global block pool: leaves [NB, Hkv, BS, Dp] (head-major,
+    rows lane-padded to ``pool_row_width(head_dim)``; DESIGN.md §Block
+    pool layout), with a leading [L] axis when ``layers`` is given.
+    ``kv_dtype="int8"`` returns the quantized layout (zero scales
+    [.., NB, Hkv, BS], so garbage blocks dequantize to exact zeros)."""
     assert kv_dtype in KV_DTYPES, kv_dtype
-    shape = (num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = (num_blocks, cfg.num_kv_heads, block_size,
+             pool_row_width(cfg.head_dim))
+    if layers is not None:
+        shape = (layers,) + shape
     if kv_dtype == "int8":
         sshape = shape[:-1]
         return QuantKVCache(jnp.zeros(shape, jnp.int8),

@@ -36,7 +36,9 @@ class Model:
     # Paged (block-table) serving path — decoder-only full-attention
     # families; None elsewhere (ssm/rwkv recurrent state and sliding-window
     # ring buffers keep the monolithic layout).
-    #   init_paged_cache(num_blocks, block_size) -> pool [L, NB, BS, Hkv, Dh]
+    #   init_paged_cache(num_blocks, block_size) -> pool [L, NB, Hkv, BS, Dp]
+    #       (head-major blocks, rows lane-padded to Dp = head_dim rounded
+    #       up to 128; int8 pools add f32 scales [L, NB, Hkv, BS])
     #   decode_step_paged(params, pool, token, block_tables, pos)
     #       -> (logits, pool)
     init_paged_cache: Optional[Callable[..., Any]] = None

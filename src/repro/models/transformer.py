@@ -210,7 +210,7 @@ def forward_decode_paged(p, cfg: ModelConfig, token, pool: KVCache,
                          attn_backend: str = "dense",
                          attn_interpret: bool = False,
                          attn_num_work=None):
-    """token [B] int32; pool leaves [L, NB, BS, Hkv, Dh] (global block
+    """token [B] int32; pool leaves [L, NB, Hkv, BS, Dp] (global block
     pool); block_tables [B, NBT] int32; pos [B] int32 (-1 = dead slot).
     Returns (logits [B, V], new_pool). The attn_* knobs are static
     backend selectors (DESIGN.md §Decode hot path), baked in by the
@@ -255,7 +255,7 @@ def forward_prefill_chunk(p, cfg: ModelConfig, tokens, pool: KVCache,
                           attn_interpret: bool = False):
     """One prompt *chunk* through the stack against the paged pool
     (DESIGN.md §Chunked prefill): tokens [B, C] int32 (rows past
-    ``chunk_len`` are padding), pool leaves [L, NB, BS, Hkv, Dh],
+    ``chunk_len`` are padding), pool leaves [L, NB, Hkv, BS, Dp],
     block_tables [B, NBT], ctx_len / chunk_len traced int32 scalars (or
     [B]). Every layer writes the chunk's K/V into its pool slice and
     attends over the written context + chunk, so calling this
@@ -343,23 +343,14 @@ def forward_mixed(p, cfg: ModelConfig, dec_token, ck_tokens, pool,
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                      dtype=None, kv_dtype: str = "bf16"):
-    """Global paged KV pool: leaves [L, NB, BS, Hkv, Dh] (DESIGN.md
-    §Block pool) — int8 rows + f32 [L, NB, BS, Hkv] scales when
+    """Global paged KV pool: leaves [L, NB, Hkv, BS, Dp] (DESIGN.md §Block
+    pool layout) — int8 rows + f32 [L, NB, Hkv, BS] scales when
     ``kv_dtype="int8"`` (§Quantized KV blocks). Blocks are owned by
     requests via the engine's BlockAllocator; the model never sees
     ownership, only block tables."""
     assert not cfg.sliding_window, "paged cache is full-attention only"
-    assert kv_dtype in attn.KV_DTYPES, kv_dtype
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads,
-             cfg.head_dim)
-    if kv_dtype == "int8":
-        sshape = shape[:-1]
-        return attn.QuantKVCache(jnp.zeros(shape, jnp.int8),
-                                 jnp.zeros(shape, jnp.int8),
-                                 jnp.zeros(sshape, jnp.float32),
-                                 jnp.zeros(sshape, jnp.float32))
-    dt = dtype or cfg.dtype
-    return KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+    return attn.make_paged_pool(cfg, num_blocks, block_size, dtype=dtype,
+                                kv_dtype=kv_dtype, layers=cfg.num_layers)
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq: int, dtype=None) -> KVCache:

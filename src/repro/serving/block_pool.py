@@ -2,9 +2,10 @@
 now **refcounted and prefix-shared** (DESIGN.md §Prefix cache).
 
 The engine owns one global KV *pool* per model — a pytree whose leaves are
-``[L, num_blocks, block_size, Hkv, Dh]`` — and every running request owns an
-ordered list of physical block ids (its *block table*). Logical token
-position ``t`` of a request lives at ``(table[t // BS], t % BS)``.
+``[L, num_blocks, Hkv, block_size, Dp]`` (DESIGN.md §Block pool layout) —
+and every running request owns an ordered list of physical block ids (its
+*block table*). Logical token position ``t`` of a request lives in block
+``table[t // BS]`` at position ``t % BS``.
 
 ``BlockAllocator`` hands out physical blocks and tracks three quantities:
 
@@ -82,8 +83,8 @@ def prompt_chain(prompt, block_size: int,
 class HostBlockStore:
     """Capacity-bounded host-RAM tier behind the device pool (DESIGN.md
     §Multi-tier KV). Entries are keyed by chain digest and carry the
-    block's KV payload in the migration wire layout (leaves
-    ``[L, 1, BS, ...]``; int8 blocks keep their scale leaves), plus the
+    block's KV payload in pool layout (leaves ``[L, 1, Hkv, BS, ...]``;
+    int8 blocks keep their scale leaves), plus the
     parent digest and head flag needed to re-publish on promote.
 
     The store is LRU over *insertion* order (a demote re-inserts, a

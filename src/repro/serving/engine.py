@@ -5,7 +5,7 @@ invariants in DESIGN.md).
 Two cache layouts behind one scheduling surface:
 
   * **paged** (default for full-attention decoder families): a global block
-    pool with leaves ``[L, num_blocks, block_size, Hkv, Dh]`` plus a
+    pool with leaves ``[L, num_blocks, Hkv, block_size, Dp]`` plus a
     per-request block table, managed by ``BlockAllocator``. Admission gates
     on worst-case *block reservations* (``ceil(min(prompt+max_new,
     max_seq)/BS)``), physical blocks are allocated incrementally as the
@@ -54,15 +54,16 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.migration import (gather_kv_blocks, kv_bytes,
                                   scatter_kv_blocks)
 from repro.kernels.cost import pow2_bucket
 from repro.launch.mesh import make_tp_mesh
-from repro.launch.shardings import pool_spec_tree, serving_param_spec_tree
-from repro.models.attention import (QuantKVCache, dequantize_piece,
+from repro.launch.shardings import (piece_spec_tree, pool_spec_tree,
+                                    serving_param_spec_tree)
+from repro.models.attention import (QuantKVCache, blocks_to_piece,
+                                    dequantize_piece, piece_to_blocks,
                                     quantize_piece, resolve_paged_backend)
 from repro.models.model import Model, build_model
 from repro.sched.policy import park_or_recompute
@@ -341,6 +342,8 @@ class Engine:
         self._tpot_hopeless_ids: set = set()
         self.steps = 0
         self.tokens_out = 0
+        # fused device calls that advanced decodes AND prompt chunks
+        self.mixed_steps = 0
         self.peak_kv_bytes = 0.0
         # prefill cost counters (bench_prefix_cache reads them): block-work
         # actually run by prefill (Σ per chunk ceil((ctx+clen)/BS) — the
@@ -364,10 +367,10 @@ class Engine:
     # ---- serving tensor parallelism (DESIGN.md §Sharded serving) ----------
     def _smap(self, fn, in_specs, out_specs):
         """shard_map a forward over this engine's 1-D 'model' mesh.
-        ``check_rep=False``: block tables / work lists are replicated by
+        ``check_vma=False``: block tables / work lists are replicated by
         construction and the psum sites live inside the model."""
-        return shard_map(fn, self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _localize_piece(self, piece):
         """Adopt a migration piece gathered on ANOTHER engine's mesh: pull
@@ -385,7 +388,7 @@ class Engine:
         if self.tp > 1:
             return jax.device_put(host, jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s),
-                pool_spec_tree(piece)))
+                piece_spec_tree(piece)))
         return jax.tree.map(jnp.asarray, host)
 
     # ---- drain-time leak check (DESIGN.md §Fault tolerance) ---------------
@@ -549,7 +552,7 @@ class Engine:
     def _demote_snapshot(self, block_id: int):
         """Payload fetch the allocator calls when reclaiming a cached
         block with the host tier on: an ASYNC device-side slice of the
-        block ([L, 1, BS, ...]; int8 pools carry their scale leaves in
+        block ([L, 1, Hkv, BS, ...]; int8 pools carry their scale leaves in
         the same pytree). Dispatch order guarantees the copy reads the
         block BEFORE the allocation that triggered the reclaim overwrites
         it; the host transfer itself happens at ``_flush_demotes`` — off
@@ -1439,6 +1442,7 @@ class Engine:
                                  bt_ck, ctxs, clens)
             if live:
                 toks = new_tok[None]    # one horizon row for the step sync
+                self.mixed_steps += 1
             else:
                 h = 0
             chunk_completed: List[ServeRequest] = []
@@ -1600,6 +1604,33 @@ class Engine:
         self.slots[slot] = None
         self.slot_len[slot] = 0
 
+    # ---- correctness probe -------------------------------------------------
+    def prompt_logits(self, prompt) -> np.ndarray:
+        """First-step logits [V] (f32) of ``prompt`` through this engine's
+        own chunked-prefill path — its backend, mesh and chunk size — over
+        a scratch block table. The returned pool is dropped, so no request
+        or allocator state changes: a probe for comparing backends and
+        meshes on identical input, never part of serving."""
+        assert self.chunked_prefill, "prompt_logits needs chunked prefill"
+        C = self.prefill_token_budget
+        T = len(prompt)
+        padded = -(-T // C) * C
+        need = blocks_for(padded, self.block_size)
+        assert need <= self.num_blocks, "prompt exceeds the pool"
+        # pow2 table width (one compile per width class); entries past
+        # ``need`` are never reached and point at the garbage block
+        bt = jnp.minimum(jnp.arange(_next_pow2(need), dtype=jnp.int32),
+                         self.garbage_block)[None]
+        toks = np.zeros((1, padded), np.int32)
+        toks[0, :T] = prompt
+        pool = self.cache
+        for ctx in range(0, T, C):
+            clen = min(C, T - ctx)
+            logits, pool = self._prefill_chunk(
+                self.params, pool, jnp.asarray(toks[:, ctx:ctx + C]), bt,
+                jnp.int32(ctx), jnp.int32(clen))
+        return np.asarray(logits[0], np.float32)
+
     # ---- migration ----------------------------------------------------------
     def export_slot(self, slot: int):
         """(request, kv piece, kv bytes) for live migration.
@@ -1622,10 +1653,10 @@ class Engine:
         length = int(self.slot_len[slot]) - (0 if req.prefilling else 1)
         if self.paged:
             gathered = gather_kv_blocks(self.cache, self.block_tables[slot])
-            # [L, nb, BS, ...] -> [L, 1, nb*BS, ...] -> trim to length
+            # pool blocks -> [L, 1, nb*BS, Hkv, Dh] -> trim to length
             piece = jax.tree.map(
-                lambda a: a.reshape(a.shape[0], 1, -1, *a.shape[3:])[:, :, :length],
-                gathered)
+                lambda a: a[:, :, :length],
+                blocks_to_piece(gathered, self.model.cfg.head_dim))
             if isinstance(piece, QuantKVCache):
                 # wire format stays full-width: mixed bf16/int8 clusters
                 # interoperate, receivers re-quantize on import
@@ -1724,18 +1755,12 @@ def _write_slot(cache, piece, slot: int):
 
 
 def _write_prompt_blocks(pool, piece, block_ids, block_size: int):
-    """Scatter a contiguous KV piece (leaves [L, 1, T, ...]) into physical
-    blocks ``block_ids`` of a paged pool (leaves [L, NB, BS, ...]).
-    Full-precision pieces headed for an int8 pool are quantized first
-    (scale leaves [L, 1, T, Hkv] pack on dim 2 like any other leaf)."""
-    nb = len(block_ids)
+    """Scatter a contiguous KV piece (leaves [L, 1, T, Hkv, Dh]) into
+    physical blocks ``block_ids`` of a paged pool (DESIGN.md §Block pool
+    layout). Full-precision pieces headed for an int8 pool are quantized
+    first."""
     if isinstance(pool, QuantKVCache) and not isinstance(piece, QuantKVCache):
         piece = quantize_piece(piece)
-
-    def pack(p):
-        T = p.shape[2]
-        pad = [(0, 0)] * p.ndim
-        pad[2] = (0, nb * block_size - T)
-        return jnp.pad(p, pad)[:, 0].reshape(
-            p.shape[0], nb, block_size, *p.shape[3:])
-    return scatter_kv_blocks(pool, jax.tree.map(pack, piece), block_ids)
+    blocks = piece_to_blocks(piece, len(block_ids), block_size,
+                             jax.tree.leaves(pool)[0].shape[-1])
+    return scatter_kv_blocks(pool, blocks, block_ids)

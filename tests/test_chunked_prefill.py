@@ -56,7 +56,7 @@ def _chunk_case(chunks, C, H, Hkv, Dh, BS, dtype):
     NBT = max(-(-(ctx + C) // BS) for ctx, _ in chunks) + 1
     NB = Bc * NBT + 2
     perm = RNG.permutation(NB)
-    k_pool = np.zeros((NB, BS, Hkv, Dh), np.float32)
+    k_pool = np.zeros((NB, Hkv, BS, Dh), np.float32)  # head-major pool
     v_pool = np.zeros_like(k_pool)
     bt = np.full((Bc, NBT), NB - 1, np.int32)
     full = []
@@ -68,8 +68,8 @@ def _chunk_case(chunks, C, H, Hkv, Dh, BS, dtype):
         for j in range(-(-(ctx + clen) // BS)):
             pb = int(perm[pi]); pi += 1
             bt[c, j] = pb
-            k_pool[pb] = kk[j * BS:(j + 1) * BS]
-            v_pool[pb] = vv[j * BS:(j + 1) * BS]
+            k_pool[pb] = kk[j * BS:(j + 1) * BS].swapaxes(0, 1)
+            v_pool[pb] = vv[j * BS:(j + 1) * BS].swapaxes(0, 1)
     q = RNG.normal(0, 1, (Bc, C, H, Dh)).astype(np.float32)
     ref = np.zeros((Bc, C, H, Dh), np.float32)
     for c, (ctx, clen) in enumerate(chunks):
@@ -150,8 +150,10 @@ def test_real_chunked_matches_whole_prompt(setup):
                                atol=2e-5, rtol=2e-5)
     assert int(jnp.argmax(logits[0])) == int(jnp.argmax(ref_logits[0]))
     for pool_l, piece_l in zip((pool.k, pool.v), (ref_piece.k, ref_piece.v)):
-        got = np.asarray(pool_l, np.float32)[:, ids]
-        got = got.reshape(got.shape[0], -1, *got.shape[3:])[:, :T]
+        # head-major, lane-padded blocks -> token-major rows
+        got = np.asarray(pool_l, np.float32)[:, ids].swapaxes(2, 3)
+        got = got.reshape(got.shape[0], -1, *got.shape[3:])[
+            :, :T, :, :cfg.head_dim]
         np.testing.assert_allclose(got, np.asarray(piece_l, np.float32)[:, 0],
                                    atol=2e-5, rtol=2e-5)
 
@@ -207,7 +209,7 @@ def make_chunk_mock_model():
         return _logits(token, pos), cache
 
     def init_paged_cache(num_blocks, block_size):
-        return {"kv": jnp.zeros((1, num_blocks, block_size, 1, 1),
+        return {"kv": jnp.zeros((1, num_blocks, 1, block_size, 1),
                                 jnp.float32)}
 
     def init_cache(batch, seq):

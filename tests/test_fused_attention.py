@@ -46,7 +46,7 @@ def _mixed_case(segs, C, H, Hkv, Dh, BS, dtype, alias=None):
     NBT = max(max(-(-t // BS) for t in totals), 1) + 1
     NB = sum(-(-t // BS) for t in totals) + 3
     perm = RNG.permutation(NB)
-    k_pool = np.zeros((NB, BS, Hkv, Dh), np.float32)
+    k_pool = np.zeros((NB, Hkv, BS, Dh), np.float32)  # head-major pool
     v_pool = np.zeros_like(k_pool)
     bt = np.full((B, NBT), NB - 1, np.int32)
     full = []
@@ -64,8 +64,8 @@ def _mixed_case(segs, C, H, Hkv, Dh, BS, dtype, alias=None):
                 continue
             pb = int(perm[pi]); pi += 1
             bt[s, j] = pb
-            k_pool[pb] = kk[j * BS:(j + 1) * BS]
-            v_pool[pb] = vv[j * BS:(j + 1) * BS]
+            k_pool[pb] = kk[j * BS:(j + 1) * BS].swapaxes(0, 1)
+            v_pool[pb] = vv[j * BS:(j + 1) * BS].swapaxes(0, 1)
     q = RNG.normal(0, 1, (B, C, H, Dh)).astype(np.float32)
     ctx = np.asarray([s[1] - 1 if s[0] == "dec" else s[1] for s in segs],
                      np.int32)
@@ -267,8 +267,7 @@ def test_fused_mixed_step_one_attn_call_one_sync(setup, rng, monkeypatch):
 # --------------------------------------------------------------------------
 # Backend resolution + the split-pow2 cost mirror
 # --------------------------------------------------------------------------
-def test_resolve_backend_fused_auto_on_tpu_dense_elsewhere(monkeypatch):
-    monkeypatch.delenv("REPRO_PAGED_ATTN", raising=False)
+def test_resolve_backend_fused_auto_on_tpu_dense_elsewhere():
     choice, interpret = resolve_paged_backend()
     on_tpu = jax.default_backend() == "tpu"
     assert choice == ("fused" if on_tpu else "dense")

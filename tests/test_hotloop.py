@@ -38,16 +38,16 @@ def _paged_case(lengths, S, H, Hkv, Dh, BS, dtype):
     NBT = S // BS
     NB = B * NBT + 3
     perm = RNG.permutation(NB)
-    k_pool = np.zeros((NB, BS, Hkv, Dh), np.float32)
-    v_pool = np.zeros((NB, BS, Hkv, Dh), np.float32)
+    k_pool = np.zeros((NB, Hkv, BS, Dh), np.float32)   # head-major pool
+    v_pool = np.zeros((NB, Hkv, BS, Dh), np.float32)
     bt = np.zeros((B, NBT), np.int32)
     pi = 0
     for b, L in enumerate(lengths):
         for j in range(blocks_for(L, BS)):
             pb = int(perm[pi]); pi += 1
             bt[b, j] = pb
-            k_pool[pb] = k[b, j * BS:(j + 1) * BS]
-            v_pool[pb] = v[b, j * BS:(j + 1) * BS]
+            k_pool[pb] = k[b, j * BS:(j + 1) * BS].swapaxes(0, 1)
+            v_pool[pb] = v[b, j * BS:(j + 1) * BS].swapaxes(0, 1)
     to = lambda a: jnp.asarray(a, dtype)
     return (to(q), to(k), to(v), to(k_pool), to(v_pool),
             jnp.asarray(bt), jnp.asarray(lengths, jnp.int32))
@@ -150,7 +150,7 @@ def make_mock_model():
         return _logits(token, pos), cache
 
     def init_paged_cache(num_blocks, block_size):
-        return {"kv": jnp.zeros((1, num_blocks, block_size, 1, 1),
+        return {"kv": jnp.zeros((1, num_blocks, 1, block_size, 1),
                                 jnp.float32)}
 
     def init_cache(batch, seq):

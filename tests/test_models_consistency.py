@@ -56,7 +56,7 @@ def test_decode_matches_full_forward(arch):
 
 
 def test_moe_gshard_matches_dense_f64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cfg = dataclasses.replace(get_config("qwen3-moe-30b-a3b").reduced(),
                                   dtype=jnp.float64)
         p = init_moe(jax.random.PRNGKey(1), cfg)

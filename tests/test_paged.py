@@ -30,16 +30,16 @@ def _paged_case(lengths, S, H, Hkv, Dh, BS, dtype):
     NBT = S // BS
     NB = B * NBT + 3
     perm = RNG.permutation(NB)
-    k_pool = np.zeros((NB, BS, Hkv, Dh), np.float32)
-    v_pool = np.zeros((NB, BS, Hkv, Dh), np.float32)
+    k_pool = np.zeros((NB, Hkv, BS, Dh), np.float32)   # head-major pool
+    v_pool = np.zeros((NB, Hkv, BS, Dh), np.float32)
     bt = np.zeros((B, NBT), np.int32)
     pi = 0
     for b, L in enumerate(lengths):
         for j in range(blocks_for(L, BS)):
             pb = int(perm[pi]); pi += 1
             bt[b, j] = pb
-            k_pool[pb] = k[b, j * BS:(j + 1) * BS]
-            v_pool[pb] = v[b, j * BS:(j + 1) * BS]
+            k_pool[pb] = k[b, j * BS:(j + 1) * BS].swapaxes(0, 1)
+            v_pool[pb] = v[b, j * BS:(j + 1) * BS].swapaxes(0, 1)
     to = lambda a: jnp.asarray(a, dtype)
     return (to(q), to(k), to(v), to(k_pool), to(v_pool),
             jnp.asarray(bt), jnp.asarray(lengths, jnp.int32))
@@ -298,3 +298,32 @@ def test_import_rejects_when_budget_reserved(setup, rng):
     src.step()
     req, piece, _ = src.export_slot(r.slot)
     assert not dst.import_request(req, piece)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_wire_piece_block_layout_roundtrip(rng, quant):
+    """Contiguous wire piece [L, 1, T, Hkv, Dh] -> head-major lane-padded
+    pool blocks [L, nb, Hkv, BS, Dp] -> back is the identity on the T
+    real rows; the padded lanes and tail rows are zeros."""
+    from repro.models.attention import (KVCache, blocks_to_piece,
+                                        piece_to_blocks, pool_row_width,
+                                        quantize_piece)
+    L, T, Hkv, Dh, BS = 2, 21, 3, 64, 8
+    x = jnp.asarray(rng.normal(0, 1, (L, 1, T, Hkv, Dh)), jnp.float32)
+    piece = KVCache(x, 2 * x)
+    if quant:
+        piece = quantize_piece(piece)
+    nb = blocks_for(T, BS)
+    blocks = piece_to_blocks(piece, nb, BS, pool_row_width(Dh))
+    assert blocks.k.shape == (L, nb, Hkv, BS, 128)
+    assert float(jnp.abs(blocks.k[..., Dh:]).max()) == 0
+    # token t of head h lands at block t // BS, row t % BS
+    np.testing.assert_array_equal(np.asarray(blocks.k[:, 1, 2, 3, :Dh]),
+                                  np.asarray(piece.k[:, 0, BS + 3, 2]))
+    if quant:
+        assert blocks.k_scale.shape == (L, nb, Hkv, BS)
+    back = blocks_to_piece(blocks, Dh)
+    for got, want in zip(back, piece):
+        np.testing.assert_array_equal(np.asarray(got[:, :, :T]),
+                                      np.asarray(want))
+        assert float(jnp.abs(got[:, :, T:]).max()) == 0

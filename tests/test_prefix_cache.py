@@ -258,8 +258,8 @@ def _aliased_case(BS, Hkv, Dh, H, shared_blocks, lengths, dtype):
     v[1, :sh] = v[0, :sh]
     NB = B * NBT + 2
     perm = RNG.permutation(NB)
-    kp = np.zeros((NB, BS, Hkv, Dh), np.float32)
-    vp = np.zeros((NB, BS, Hkv, Dh), np.float32)
+    kp = np.zeros((NB, Hkv, BS, Dh), np.float32)       # head-major pool
+    vp = np.zeros((NB, Hkv, BS, Dh), np.float32)
     bt = np.zeros((B, NBT), np.int32)
     pi = 0
     for b, L in enumerate(lengths):
@@ -269,8 +269,8 @@ def _aliased_case(BS, Hkv, Dh, H, shared_blocks, lengths, dtype):
                 continue
             pb = int(perm[pi]); pi += 1
             bt[b, j] = pb
-            kp[pb] = k[b, j * BS:(j + 1) * BS]
-            vp[pb] = v[b, j * BS:(j + 1) * BS]
+            kp[pb] = k[b, j * BS:(j + 1) * BS].swapaxes(0, 1)
+            vp[pb] = v[b, j * BS:(j + 1) * BS].swapaxes(0, 1)
     to = lambda x: jnp.asarray(x, dtype)
     return (to(q), to(k), to(v), to(kp), to(vp),
             jnp.asarray(bt), jnp.asarray(lengths, jnp.int32))

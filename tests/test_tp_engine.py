@@ -13,6 +13,7 @@ disciplines survive the mesh.
 import jax
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro.serving.engine import Engine
 from repro.serving import engine as engine_mod
@@ -230,3 +231,69 @@ def test_migration_round_trip_between_different_tp(setup):
         ref_eng.step()
     assert r.generated == ref.generated
     assert set(r.tokens_by_engine) >= {a.id, b.id}
+
+
+# --------------------------------------------------------------------------
+# Sharded weight init and the engine's logits probe
+# --------------------------------------------------------------------------
+def test_init_params_tp2_born_sharded_same_values(setup):
+    """``init_params(tp=2)`` creates every weight straight into its
+    serving sharding (no device ever holds a sharded weight whole) with
+    the same values as the single-device init."""
+    from jax.sharding import NamedSharding
+    from repro.launch.serve import init_params
+    from repro.launch.shardings import serving_param_spec_tree
+    cfg, model, _ = setup
+    sharded = init_params(model, 0, tp=2)
+    single = init_params(model, 0)
+    specs = serving_param_spec_tree(single, 2)
+    for leaf, spec, ref in zip(jax.tree.leaves(sharded),
+                               jax.tree.leaves(specs, is_leaf=lambda x:
+                                               isinstance(x, P)),
+                               jax.tree.leaves(single)):
+        assert isinstance(leaf.sharding, NamedSharding)
+        assert leaf.sharding.spec == spec
+        if any(spec):
+            shard = next(iter(leaf.addressable_shards)).data
+            assert shard.size == leaf.size // 2
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(ref))
+
+
+@pytest.mark.parametrize("backend", ["dense", "fused"])
+def test_prompt_logits_probe_tp2_matches_tp1(setup, backend):
+    """``Engine.prompt_logits`` runs a prompt through the engine's own
+    chunked-prefill path (several chunks here) without touching request
+    state: tp=2 agrees with tp=1, its argmax is the first token the
+    engine then serves, and the pool and allocator are unchanged."""
+    cfg, model, params = setup
+    e1 = _engine(model, params, 1, prefill_token_budget=8)
+    e2 = _engine(model, params, 2, prefill_token_budget=8,
+                 attn_backend=backend)
+    prompt = np.random.default_rng(3).integers(0, cfg.vocab_size, 21)
+    free = e2.free_tokens()
+    l1, l2 = e1.prompt_logits(prompt), e2.prompt_logits(prompt)
+    np.testing.assert_allclose(l2, l1, atol=2e-5, rtol=2e-5)
+    assert e2.free_tokens() == free
+    assert float(np.abs(np.asarray(jax.tree.leaves(e2.cache)[0])).max()) == 0
+    r = ServeRequest(0, prompt.astype(np.int32), 2)
+    _drive(e2, [r])
+    assert r.generated[0] == int(np.argmax(l2))
+
+
+def test_build_server_full_stack_tp2(setup):
+    """``launch.serve.build_server`` (the launcher's and chip smoke's
+    factory) builds a tp=2 cascade cluster whose weights are sharded at
+    birth, and serves a mixed-length trace to completion."""
+    from repro.launch.serve import build_server
+    from repro.serving.server import ServerConfig
+    cfg, model, params = setup
+    srv = build_server(cfg, ServerConfig(policy="cascade"), engines=1, tp=2,
+                       max_seq=96, max_slots=4, attn_backend="fused")
+    eng = srv.engines[0]
+    assert eng.tp == 2 and eng.fused_mixed
+    assert any(next(iter(w.addressable_shards)).data.size == w.size // 2
+               for w in jax.tree.leaves(eng.params))
+    reqs = _mkreqs(cfg.vocab_size, SHAPES)
+    srv.run(reqs, max_steps=400)
+    assert len(srv.finished) == len(reqs)
+    assert all(len(r.generated) == r.max_new_tokens for r in srv.finished)
