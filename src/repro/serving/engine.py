@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 import weakref
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
@@ -54,6 +55,8 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+# host spans of a step's phases (DESIGN.md §Tracing)
+from jax.profiler import TraceAnnotation as _span
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.migration import (gather_kv_blocks, kv_bytes,
@@ -356,10 +359,16 @@ class Engine:
         # multi-tier KV counters (DESIGN.md §Multi-tier KV): blocks
         # promoted from the host tier back onto device at admission
         self.promoted_blocks_total = 0
-        # last decode's grid accounting (bench_decode_hotloop reads it):
-        # flat_items = work items the flat grid runs (pow2 bucket),
-        # real_items = Σ_b ceil(L_b/BS), padded_items = B·max_b ceil(L_b/BS)
-        self.last_grid: Dict[str, int] = {}
+        # work-list accounting of the device loop's launches (the
+        # ``engine.launch`` span's ``items``/``real_items``, summed):
+        # work items launched (the pow2 bucket the flat/fused kernels run,
+        # x the horizon) and those holding a real KV block
+        self.work_items = 0
+        self.real_work_items = 0
+        # first admissions of requests a server submitted, and the sum of
+        # their queue waits (host clock, submit to admission)
+        self.admitted_total = 0
+        self.queue_wait_s_total = 0.0
         self._prefill = jax.jit(model.prefill,
                                 static_argnames=("cache_len",))
         _LIVE_ENGINES.append(weakref.ref(self))
@@ -709,11 +718,23 @@ class Engine:
                 break
             slot = self._free_slot()
             self.waiting.popleft()
+            self._stamp_admit(req)
             self._prefill_into_slot(req, slot)
             admitted.append(req)
         if self.slo_sched:
             self._resume_ready()
         return admitted
+
+    def _stamp_admit(self, req: ServeRequest) -> None:
+        """First admission: stamp ``t_admit`` and, for a request a server
+        submitted, count its queue wait. Re-admissions (recompute resumes,
+        redispatch after a crash) keep the first stamp."""
+        if req.t_admit is not None:
+            return
+        req.t_admit = time.perf_counter()
+        if req.t_submit is not None:
+            self.admitted_total += 1
+            self.queue_wait_s_total += req.t_admit - req.t_submit
 
     def _reserve(self, req: ServeRequest, slot: int,
                  cached_blocks: int = 0) -> None:
@@ -772,6 +793,7 @@ class Engine:
         vec = logits if logits.ndim == 1 else logits[0]
         tok = int(d2h(jnp.argmax(vec)))
         req.generated.append(tok)
+        req.t_first_token = time.perf_counter()
         req.ctx_done = len(req.prompt)
         req.first_token_step = self.steps
         req.state = State.RUNNING
@@ -880,6 +902,7 @@ class Engine:
                 break
             slot = self._free_slot()
             self.waiting.popleft()
+            self._stamp_admit(req)
             # longest cached chain across both tiers: device blocks are
             # shared (refcount++, zero copies), host-tier continuations
             # PROMOTE — fresh owned blocks under this request's
@@ -1001,6 +1024,7 @@ class Engine:
                 self._pending_first.append((req, tok_dev))
             else:
                 req.generated.append(int(d2h(tok_dev)))
+                req.t_first_token = time.perf_counter()
             completed.append(req)
 
     # ---- SLO preemption (DESIGN.md §SLO scheduling & preemption) -------------
@@ -1366,6 +1390,22 @@ class Engine:
         self._mixed_fns[num_work] = fn
         return fn
 
+    def _grow_tables(self, live, h: int) -> int:
+        """Pre-grow the live rows' block tables to cover every write of an
+        ``h``-iteration step (positions slot_len-1 .. slot_len+h-2) —
+        covered by the admission reservations, so allocation cannot fail
+        — with one device write per grown row. Returns the rows grown."""
+        rows = 0
+        for i, _ in live:
+            need = blocks_for(int(self.slot_len[i]) + h - 1, self.block_size)
+            table = self.block_tables[i]
+            if need > len(table):
+                table.extend(self.allocator.allocate(need - len(table)))
+                self._ensure_nbt_cap(need)
+                self._dev_set_table(i, table)
+                rows += 1
+        return rows
+
     def _step_device(self, burst: int) -> List[ServeRequest]:
         self.steps += 1
         base = self.steps                  # engine step of the 1st iteration
@@ -1373,31 +1413,40 @@ class Engine:
         self._pending_first = []
         prefill_done: List[ServeRequest] = []
         chunk_plan: List[Tuple[int, int]] = []
-        if self.chunked_prefill:
-            if self.fused_mixed:
-                # plan + admit only — the chunks execute INSIDE the fused
-                # mixed call below, not as a separate device call
-                rejected, chunk_plan = self._plan_chunks()
-                finished.extend(rejected)
+        admitted0, wait0 = self.admitted_total, self.queue_wait_s_total
+        # admission, preemption and chunk planning (the separate-kernel and
+        # whole-prompt paths run their prefill calls in here too)
+        with _span("engine.plan", eng=self.id) as sp:
+            if self.chunked_prefill:
+                if self.fused_mixed:
+                    # plan + admit only — the chunks execute INSIDE the
+                    # fused mixed call below, not as a separate device call
+                    rejected, chunk_plan = self._plan_chunks()
+                    finished.extend(rejected)
+                else:
+                    rejected, prefilled = self._run_chunked_prefill()
+                    finished.extend(rejected)
+                    for r in prefilled:
+                        if r.max_new_tokens <= 1:   # finishes at prefill;
+                            prefill_done.append(r)  # its token lands after
+                            self._release(r.slot)   # the sync
             else:
-                rejected, prefilled = self._run_chunked_prefill()
-                finished.extend(rejected)
-                for r in prefilled:
-                    if r.max_new_tokens <= 1:   # finishes at prefill; its
-                        prefill_done.append(r)  # token lands after the sync
-                        self._release(r.slot)
-        else:
-            for r in self._admit():
-                if r.rejected:                  # prompt can never fit
-                    finished.append(r)
-                elif r.max_new_tokens <= 1:     # finishes at prefill; its
-                    prefill_done.append(r)      # token lands after the sync
-                    self._release(r.slot)
-        # requests still mid-prefill hold their slot but do NOT decode:
-        # their device table row stays all-garbage and their length 0, so
-        # the fixed-shape batch treats them as dead slots
-        live = [(i, r) for i, r in enumerate(self.slots)
-                if r is not None and not r.prefilling]
+                for r in self._admit():
+                    if r.rejected:              # prompt can never fit
+                        finished.append(r)
+                    elif r.max_new_tokens <= 1:     # finishes at prefill;
+                        prefill_done.append(r)      # its token lands after
+                        self._release(r.slot)       # the sync
+            # requests still mid-prefill hold their slot but do NOT
+            # decode: their device table row stays all-garbage and their
+            # length 0, so the fixed-shape batch treats them as dead slots
+            live = [(i, r) for i, r in enumerate(self.slots)
+                    if r is not None and not r.prefilling]
+            if _span.is_enabled():
+                sp.set_metadata(
+                    admitted=self.admitted_total - admitted0,
+                    wait_us=round((self.queue_wait_s_total - wait0) * 1e6),
+                    chunk_tokens=sum(c for _, c in chunk_plan))
         h = 0
         toks = None
         if self.fused_mixed and chunk_plan:
@@ -1406,142 +1455,156 @@ class Engine:
             # a step with chunk work is an admission opportunity, so it
             # never bursts (same rule as the separate path's cap)
             h = 1
-            # pre-grow decode tables for this step's write (pos slot_len-1)
-            for i, _ in live:
-                need = blocks_for(int(self.slot_len[i]), self.block_size)
-                table = self.block_tables[i]
-                if need > len(table):
-                    table.extend(self.allocator.allocate(need - len(table)))
-                    self._ensure_nbt_cap(need)
-                    self._dev_set_table(i, table)
-            ck_toks, bt_ck, ctxs, clens = \
-                self._prepare_chunk_arrays(chunk_plan)
-            dec_blocks = [blocks_for(int(self.slot_len[i]), self.block_size)
-                          for i, _ in live]
-            ck_blocks = [blocks_for(self.slots[s].ctx_done + c,
-                                    self.block_size) for s, c in chunk_plan]
-            real = sum(dec_blocks) + sum(ck_blocks)
-            # bucket = pow2(decode items) + pow2(chunk items), NOT
-            # pow2(sum): the padding tail then never exceeds what the two
-            # separate kernels would pad (pow2(a+b) can overshoot
-            # pow2(a)+pow2(b)), so fusing strictly saves the launch; the
-            # jit cache stays O(log²) keys
-            num_work = ((_next_pow2(sum(dec_blocks)) if live else 0)
-                        + _next_pow2(sum(ck_blocks)))
-            self.last_grid = {
-                "backend": "fused",
-                "flat_items": num_work,
-                "real_items": real,
-                "padded_items": (len(dec_blocks) + len(ck_blocks))
-                * max(dec_blocks + ck_blocks),
-            }
-            fn = self._mixed_fn(num_work)
-            (self.cache, self._dev_tok, self._dev_len, new_tok,
-             ck_tok) = attn_call(fn, self.params, self.cache, self._dev_bt,
-                                 self._dev_tok, self._dev_len, ck_toks,
-                                 bt_ck, ctxs, clens)
-            if live:
-                toks = new_tok[None]    # one horizon row for the step sync
-                self.mixed_steps += 1
-            else:
-                h = 0
-            chunk_completed: List[ServeRequest] = []
-            self._finish_chunks(chunk_plan, ck_tok, chunk_completed)
-            for r in chunk_completed:
-                if r.max_new_tokens <= 1:       # finishes at prefill; its
-                    prefill_done.append(r)      # token lands after the sync
-                    self._release(r.slot)
+            with _span("engine.tables", eng=self.id) as sp:
+                rows = self._grow_tables(live, h)
+                if _span.is_enabled():
+                    sp.set_metadata(rows=rows)
+            with _span("engine.stage", eng=self.id):
+                ck_toks, bt_ck, ctxs, clens = \
+                    self._prepare_chunk_arrays(chunk_plan)
+            # the launch, and the device writes that hand the prompts it
+            # completes to the decode batch
+            with _span("engine.launch", eng=self.id, kind="mixed") as sp:
+                dec_blocks = [blocks_for(int(self.slot_len[i]),
+                                         self.block_size) for i, _ in live]
+                ck_blocks = [blocks_for(self.slots[s].ctx_done + c,
+                                        self.block_size)
+                             for s, c in chunk_plan]
+                real = sum(dec_blocks) + sum(ck_blocks)
+                # bucket = pow2(decode items) + pow2(chunk items), NOT
+                # pow2(sum): the padding tail then never exceeds what the
+                # two separate kernels would pad (pow2(a+b) can overshoot
+                # pow2(a)+pow2(b)), so fusing strictly saves the launch;
+                # the jit cache stays O(log²) keys
+                num_work = ((_next_pow2(sum(dec_blocks)) if live else 0)
+                            + _next_pow2(sum(ck_blocks)))
+                self.work_items += num_work
+                self.real_work_items += real
+                if _span.is_enabled():
+                    sp.set_metadata(horizon=h, items=num_work,
+                                    real_items=real)
+                fn = self._mixed_fn(num_work)
+                (self.cache, self._dev_tok, self._dev_len, new_tok,
+                 ck_tok) = attn_call(fn, self.params, self.cache,
+                                     self._dev_bt, self._dev_tok,
+                                     self._dev_len, ck_toks, bt_ck, ctxs,
+                                     clens)
+                if live:
+                    toks = new_tok[None]    # one horizon row for the sync
+                    self.mixed_steps += 1
+                else:
+                    h = 0
+                chunk_completed: List[ServeRequest] = []
+                self._finish_chunks(chunk_plan, ck_tok, chunk_completed)
+                for r in chunk_completed:
+                    if r.max_new_tokens <= 1:   # finishes at prefill; its
+                        prefill_done.append(r)  # token lands after the sync
+                        self._release(r.slot)
         elif live:
-            pend_reqs = {id(r) for r, _ in self._pending_first}
-            # fusion horizon: nobody may cross a count/capacity finish
-            # boundary before the last fused iteration (eos finishes are
-            # data-dependent and handled by truncation after the sync)
-            def _until_finish(i, r):
-                gen = len(r.generated) + (1 if id(r) in pend_reqs else 0)
-                return min(r.max_new_tokens - gen,
-                           self.max_seq - int(self.slot_len[i]))
-            # only NO-admission steps fuse: with a non-empty queue (or a
-            # prompt mid-chunked-prefill) every step is an admission /
-            # chunk opportunity, so stay at h=1 — this is also what caps a
-            # decode request's inter-token gap at ONE mixed iteration
-            cap = 1 if (self.waiting or self._prefill_order
-                        or self.parked) else burst
-            h = max(1, min([cap] + [_until_finish(i, r) for i, r in live]))
-            h = _pow2_floor(h)
-            # pre-grow block tables to cover every write of the burst
-            # (positions slot_len-1 .. slot_len+h-2) — covered by the
-            # admission reservations, so allocation cannot fail
-            for i, _ in live:
-                need = blocks_for(int(self.slot_len[i]) + h - 1,
-                                  self.block_size)
-                table = self.block_tables[i]
-                if need > len(table):
-                    table.extend(self.allocator.allocate(need - len(table)))
-                    self._ensure_nbt_cap(need)
-                    self._dev_set_table(i, table)   # one write per grown row
-            real = sum(blocks_for(int(self.slot_len[i]) + h - 1,
-                                  self.block_size) for i, _ in live)
-            # num_work only shapes the flat-work-list grids (flat/fused);
-            # for the other backends key the jit cache on a single value so
-            # pow2 growth of the live block count never forces a recompile
-            num_work = (_next_pow2(real)
-                        if self.attn_backend in ("flat", "fused") else 0)
-            self.last_grid = {
-                "backend": self.attn_backend,
-                "flat_items": _next_pow2(real),
-                "real_items": sum(blocks_for(int(self.slot_len[i]),
-                                             self.block_size)
-                                  for i, _ in live),
-                "padded_items": len(live) * max(
-                    blocks_for(int(self.slot_len[i]), self.block_size)
-                    for i, _ in live),
-            }
-            fn = self._burst_fn(num_work, h)
-            self.cache, self._dev_tok, self._dev_len, toks = attn_call(
-                fn, self.params, self.cache, self._dev_bt, self._dev_tok,
-                self._dev_len)
+            # the burst's horizon, and the block tables grown to cover it
+            with _span("engine.tables", eng=self.id) as sp:
+                pend_reqs = {id(r) for r, _ in self._pending_first}
+
+                # fusion horizon: nobody may cross a count/capacity finish
+                # boundary before the last fused iteration (eos finishes
+                # are data-dependent and handled by truncation after the
+                # sync)
+                def _until_finish(i, r):
+                    gen = len(r.generated) + (1 if id(r) in pend_reqs
+                                              else 0)
+                    return min(r.max_new_tokens - gen,
+                               self.max_seq - int(self.slot_len[i]))
+                # only NO-admission steps fuse: with a non-empty queue (or
+                # a prompt mid-chunked-prefill) every step is an admission
+                # / chunk opportunity, so stay at h=1 — this is also what
+                # caps a decode request's inter-token gap at ONE mixed
+                # iteration
+                cap = 1 if (self.waiting or self._prefill_order
+                            or self.parked) else burst
+                h = max(1, min([cap] + [_until_finish(i, r)
+                                        for i, r in live]))
+                h = _pow2_floor(h)
+                rows = self._grow_tables(live, h)
+                if _span.is_enabled():
+                    sp.set_metadata(rows=rows)
+            with _span("engine.launch", eng=self.id, kind="burst") as sp:
+                lens = [int(self.slot_len[i]) for i, _ in live]
+                # the work list covers the burst's longest contexts;
+                # iteration s attends over slot_len + s rows
+                items = _next_pow2(sum(blocks_for(n + h - 1, self.block_size)
+                                       for n in lens))
+                real = sum(blocks_for(n + s, self.block_size)
+                           for s in range(h) for n in lens)
+                self.work_items += items * h
+                self.real_work_items += real
+                if _span.is_enabled():
+                    sp.set_metadata(horizon=h, items=items * h,
+                                    real_items=real)
+                # num_work only shapes the flat-work-list grids
+                # (flat/fused); for the other backends key the jit cache on
+                # a single value so pow2 growth of the live block count
+                # never forces a recompile
+                num_work = (items if self.attn_backend in ("flat", "fused")
+                            else 0)
+                fn = self._burst_fn(num_work, h)
+                self.cache, self._dev_tok, self._dev_len, toks = attn_call(
+                    fn, self.params, self.cache, self._dev_bt, self._dev_tok,
+                    self._dev_len)
         # ---- the step's single device->host transfer ----
-        pending = list(self._pending_first)
-        parts = [jnp.stack([t for _, t in pending])] if pending else []
-        if toks is not None:
-            parts.append(toks.reshape(-1))
-        host = d2h(jnp.concatenate(parts)) if parts else np.zeros(0, np.int32)
-        first = host[:len(pending)]
-        rest = host[len(pending):].reshape(h, self.max_slots) if h else None
-        # prefill first tokens (deferred appends)
-        for (r, _), tok in zip(pending, first):
-            r.generated.append(int(tok))
-        for r in prefill_done:
-            r.state = State.FINISHED
-            r.finish_step = base
-            finished.append(r)
-        # an admitted request whose FIRST token was eos is done before the
-        # burst tokens; its fused decodes wrote only its own pre-grown
-        # blocks, so truncating here is safe
-        for i, r in live:
-            if r.state is State.RUNNING and r.done:
+        with _span("engine.d2h", eng=self.id):
+            pending = list(self._pending_first)
+            parts = [jnp.stack([t for _, t in pending])] if pending else []
+            if toks is not None:
+                parts.append(toks.reshape(-1))
+            host = (d2h(jnp.concatenate(parts)) if parts
+                    else np.zeros(0, np.int32))
+        with _span("engine.commit", eng=self.id) as sp:
+            now = time.perf_counter()
+            first = host[:len(pending)]
+            rest = host[len(pending):].reshape(h, self.max_slots) if h \
+                else None
+            # prefill first tokens (deferred appends)
+            for (r, _), tok in zip(pending, first):
+                r.generated.append(int(tok))
+                if r.t_first_token is None:
+                    r.t_first_token = now
+            for r in prefill_done:
                 r.state = State.FINISHED
                 r.finish_step = base
                 finished.append(r)
-                self._release(i)
-        for s in range(h):
+            # an admitted request whose FIRST token was eos is done before
+            # the burst tokens; its fused decodes wrote only its own
+            # pre-grown blocks, so truncating here is safe
             for i, r in live:
-                if r.state is State.FINISHED:
-                    continue
-                r.generated.append(int(rest[s, i]))
-                r.tokens_by_engine[self.id] = \
-                    r.tokens_by_engine.get(self.id, 0) + 1
-                self.tokens_out += 1
-                self.slot_len[i] += 1
-                if r.done or self.slot_len[i] >= self.max_seq:
+                if r.state is State.RUNNING and r.done:
                     r.state = State.FINISHED
-                    r.finish_step = base + s
+                    r.finish_step = base
                     finished.append(r)
                     self._release(i)
-        self.steps = base + max(h - 1, 0)
-        self._flush_demotes()
-        self.peak_kv_bytes = max(self.peak_kv_bytes, self.kv_bytes_pinned())
-        assert self.free_tokens() >= 0, "admission let the budget go negative"
+            for s in range(h):
+                for i, r in live:
+                    if r.state is State.FINISHED:
+                        continue
+                    r.generated.append(int(rest[s, i]))
+                    r.tokens_by_engine[self.id] = \
+                        r.tokens_by_engine.get(self.id, 0) + 1
+                    self.tokens_out += 1
+                    self.slot_len[i] += 1
+                    if r.done or self.slot_len[i] >= self.max_seq:
+                        r.state = State.FINISHED
+                        r.finish_step = base + s
+                        finished.append(r)
+                        self._release(i)
+            self.steps = base + max(h - 1, 0)
+            if _span.is_enabled():
+                sp.set_metadata(finished=len(finished))
+        # the demote flush, and the step's pool accounting
+        with _span("engine.flush", eng=self.id):
+            self._flush_demotes()
+            self.peak_kv_bytes = max(self.peak_kv_bytes,
+                                     self.kv_bytes_pinned())
+            assert self.free_tokens() >= 0, \
+                "admission let the budget go negative"
         return finished
 
     def _decode_mono_live(self, live, last_tok, pos):

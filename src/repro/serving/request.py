@@ -76,6 +76,14 @@ class ServeRequest:
     # queue key is promoted one class per elapsed TTFT budget
     # (sched.slo.aging_promotion). None = never recompute-preempted.
     preempted_step: Optional[int] = None
+    # host-clock stamps (time.perf_counter), only ever subtracted from one
+    # another: submitted to a server, first admitted to an engine, first
+    # token on the host (after the step's device->host copy)
+    t_submit: Optional[float] = dataclasses.field(default=None,
+                                                  compare=False)
+    t_admit: Optional[float] = dataclasses.field(default=None, compare=False)
+    t_first_token: Optional[float] = dataclasses.field(default=None,
+                                                       compare=False)
 
     @property
     def length(self) -> int:

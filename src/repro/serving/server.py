@@ -22,9 +22,11 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import time
 from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple)
 
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.control import (MIG_COMPLETED, MIG_FAILED, MIG_STARTED, XFER_OK,
                            XFER_STALL, ControlConfig, ControlPlane,
@@ -184,10 +186,11 @@ class _ServerOps:
         slot = req.slot
         if slot is None or src.slots[slot] is not req:
             return MIG_FAILED
-        _, piece, _ = src.export_slot(slot)
-        if not dst.import_request(req, piece):
-            return MIG_FAILED
-        src.evict_slot(slot)
+        with _span("plane.migrate", req=req.req_id, src=src_id, dst=dst_id):
+            _, piece, _ = src.export_slot(slot)
+            if not dst.import_request(req, piece):
+                return MIG_FAILED
+            src.evict_slot(slot)
         return MIG_COMPLETED
 
     def set_boundary(self, stage_idx: int, hi: float) -> None:
@@ -348,12 +351,14 @@ class MILSServer:
     def submit(self, req: ServeRequest) -> None:
         """Closed-loop submission: the request arrives now."""
         req.arrival_step = self.steps
+        req.t_submit = time.perf_counter()
         self.submitted += 1
-        digest, cached, price = self._prefix_hint(req)
-        self.plane.submit(req, req.req_id, float(len(req.prompt)),
-                          cached_tokens=cached, prefix_digest=digest,
-                          promote_cost_tokens=price,
-                          slo_class=req.slo_class)
+        with _span("server.route", req=req.req_id):
+            digest, cached, price = self._prefix_hint(req)
+            self.plane.submit(req, req.req_id, float(len(req.prompt)),
+                              cached_tokens=cached, prefix_digest=digest,
+                              promote_cost_tokens=price,
+                              slo_class=req.slo_class)
 
     def submit_at(self, req: ServeRequest, step: int) -> None:
         """Open-loop submission: the request arrives at ``step`` (replays
@@ -366,6 +371,7 @@ class MILSServer:
         while self._schedule and self._schedule[0][0] <= self.steps:
             _, _, req = heapq.heappop(self._schedule)
             req.arrival_step = self.steps
+            req.t_submit = time.perf_counter()
             digest, cached, price = self._prefix_hint(req)
             self.plane.submit(req, req.req_id, float(len(req.prompt)),
                               cached_tokens=cached, prefix_digest=digest,
@@ -449,30 +455,41 @@ class MILSServer:
                 continue
             fin = eng.step()
             done.extend(fin)
-            self._stream(eng.active())
-            self._stream(fin)
+            with _span("server.stream"):
+                self._stream(eng.active())
+                self._stream(fin)
         self.finished.extend(done)
         for r in done:
             self._emitted.pop(r.req_id, None)
         if self.cfg.policy == "cascade":
-            self.plane.begin_tick()
-            if self.cfg.faults is not None:
-                # liveness runs only on fault-aware servers, so legacy
-                # runs stay bit-identical to the pre-fault server
-                for eng in self.engines:
-                    if eng.id not in self.crashed:
-                        self.plane.heartbeat(eng.id, float(self.steps))
-                self.plane.check_liveness(float(self.steps))
-            self.plane.handover_all()
-            if self.steps % self.cfg.balance_every == 0:
-                self.plane.balance()
-            if self.steps % self.cfg.refine_every == 0:
-                self.plane.refine()
-            # retry offers deferred by §5 flow control / the tick budget —
-            # without this an offer put back in a receiver queue would only
-            # be retried if a later offer happened to land on that receiver
-            self.plane.pump_all()
+            with _span("plane.tick") as sp:
+                mig0 = self.plane.migrations
+                self._tick()
+                if _span.is_enabled():
+                    sp.set_metadata(handovers=self.plane.migrations - mig0)
         return done
+
+    def _tick(self) -> None:
+        """The cascade control plane's work after the engines' steps:
+        liveness, growth handover, balance, boundary refinement, and the
+        deferred offers (migrations run inside, as KV export -> import)."""
+        self.plane.begin_tick()
+        if self.cfg.faults is not None:
+            # liveness runs only on fault-aware servers, so legacy runs
+            # stay bit-identical to the pre-fault server
+            for eng in self.engines:
+                if eng.id not in self.crashed:
+                    self.plane.heartbeat(eng.id, float(self.steps))
+            self.plane.check_liveness(float(self.steps))
+        self.plane.handover_all()
+        if self.steps % self.cfg.balance_every == 0:
+            self.plane.balance()
+        if self.steps % self.cfg.refine_every == 0:
+            self.plane.refine()
+        # retry offers deferred by §5 flow control / the tick budget —
+        # without this an offer put back in a receiver queue would only be
+        # retried if a later offer happened to land on that receiver
+        self.plane.pump_all()
 
     def run(self, requests: Sequence[ServeRequest] = (),
             max_steps: int = 2000, drain: bool = True) -> List[ServeRequest]:
@@ -533,6 +550,17 @@ class MILSServer:
             for name, arr in (("ttft_steps", ttft), ("e2e_steps", e2e)):
                 out[f"{name}_mean"] = float(arr.mean())
                 for p in (50, 95, 99):
+                    out[f"{name}_p{p}"] = float(np.percentile(arr, p))
+        # the same on the host clock, in seconds from submission: queue
+        # wait to first admission, and time to the first token on the host
+        timed = [r for r in served if r.t_submit is not None
+                 and r.t_admit is not None and r.t_first_token is not None]
+        if timed:
+            for name, arr in (
+                    ("queue_wait_s", [r.t_admit - r.t_submit for r in timed]),
+                    ("ttft_s", [r.t_first_token - r.t_submit
+                                for r in timed])):
+                for p in (50, 95):
                     out[f"{name}_p{p}"] = float(np.percentile(arr, p))
         # per-class SLO attainment + goodput-under-SLO, through the SAME
         # formula the simulator reports (sim.metrics.class_slo_summary) —

@@ -102,6 +102,27 @@ def test_open_loop_arrivals_stream_and_tail_metrics(setup, rng):
                if k.startswith("migrations_s")) == s["migrations"]
 
 
+def test_summary_wall_clock_latency(setup, rng):
+    """Queue wait and TTFT in seconds on the host clock, from submission:
+    stamped whether or not a token callback is set."""
+    cfg, model, params = setup
+    srv = MILSServer(model, params, _plan(2), _qoe(),
+                     ServerConfig(policy="cascade", seed=0),
+                     max_slots=3, max_seq=96)
+    fin = srv.run(_reqs(rng, cfg, 6, new=(4, 12)), max_steps=300)
+    assert len(fin) == 6
+    s = srv.summary()
+    for name in ("queue_wait_s", "ttft_s"):
+        assert 0 <= s[f"{name}_p50"] <= s[f"{name}_p95"]
+    assert s["queue_wait_s_p50"] <= s["ttft_s_p50"]
+    assert s["queue_wait_s_p95"] <= s["ttft_s_p95"]
+    assert all(r.t_submit <= r.t_admit <= r.t_first_token for r in fin)
+    # the engines' counters hold the same waits
+    assert sum(e.admitted_total for e in srv.engines) == 6
+    assert sum(e.queue_wait_s_total for e in srv.engines) == pytest.approx(
+        sum(r.t_admit - r.t_submit for r in fin))
+
+
 @pytest.mark.parametrize("refinement,balancing",
                          [("quantity", "full"), ("memory", "inter-stage"),
                           ("none", "rr")])
