@@ -99,7 +99,7 @@ def run_scenario(model, params, *, backend, kv_dtype, long_prompt, budget,
     while any(r.finish_step is None for r in decode):
         eng.step()
     # mixed steps = chunk work beside a live decode batch; drop compile
-    # steps (num_work/chunk-bucket retraces) via the median
+    # steps (table-width/chunk-bucket retraces) via the median
     return {
         "backend": backend,
         "kv_dtype": kv_dtype,
@@ -172,7 +172,7 @@ def main() -> None:
     out["mixed_step_speedup"] = speedup
     # analytic kernel mirror of the SAME mixed-iteration shape: the decode
     # batch mid-longtail plus one budget-sized chunk halfway through the
-    # long prompt — identical padding tails, one launch vs two
+    # long prompt — one launch over the real items vs two padded ones
     spec = AttnSpec(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
                     block_s=DEFAULT_BLOCK_SIZE)
     plens = np.geomspace(7, 700, args.decode_reqs).astype(int)

@@ -199,23 +199,6 @@ def prefill_chunk_attn_time_s(chunk: int, ctx: int, spec: AttnSpec) -> float:
     return max(dma, mxu)
 
 
-def fused_grid_items(chunks: Sequence[tuple], decode_lengths: Sequence[int],
-                     block_s: int) -> int:
-    """Grid steps (per kv head) of the FUSED mixed-iteration work list:
-    pow2 bucket of the decode rows' real blocks PLUS pow2 bucket of the
-    chunk blocks. The engine buckets the two halves independently rather
-    than pow2(dec+ck) — a single bucket can overshoot the pair (e.g.
-    9+8 → 32 vs 16+8), which would let the merged grid pay MORE padding
-    than the two kernels it replaces; with split buckets the padding tail
-    is identical by construction and fusing saves exactly the extra
-    launch (DESIGN.md §Fused mixed-iteration attention)."""
-    dec = ragged_blocks(decode_lengths, block_s)
-    ck = sum(prefill_chunk_blocks(int(c), int(x), block_s)
-             for c, x in chunks)
-    return ((pow2_bucket(dec) if dec else 0)
-            + (pow2_bucket(ck) if ck else 0))
-
-
 def mixed_iter_time_s(chunks: Sequence[tuple], decode_lengths: Sequence[int],
                       spec: AttnSpec, *,
                       decode_backend: str = "flat") -> float:
@@ -227,18 +210,13 @@ def mixed_iter_time_s(chunks: Sequence[tuple], decode_lengths: Sequence[int],
     ``padded``) so a chunked-vs-monolithic comparison can hold the decode
     backend fixed and attribute only the prefill difference to chunking.
 
-    ``fused`` prices the single tagged work list: one launch carrying the
-    same decode + chunk padding tails the separate kernels pad. The
-    separate backends pay the chunk grid's own padding tail PLUS the
-    extra chunk-batch launch (``LAUNCH_OVERHEAD_S``)."""
+    ``fused`` prices the single tagged work list: one launch over the real
+    decode and chunk items, with no padding tail. The separate backends
+    pay the decode and chunk grids' pow2 padding tails PLUS the extra
+    chunk-batch launch (``LAUNCH_OVERHEAD_S``)."""
     if decode_backend == "fused":
         comp_dec = ragged_blocks(decode_lengths, spec.block_s)
-        comp_ck = sum(prefill_chunk_blocks(int(c), int(x), spec.block_s)
-                      for c, x in chunks)
-        skipped = max(fused_grid_items(chunks, decode_lengths, spec.block_s)
-                      - comp_dec - comp_ck, 0)
-        t = spec.num_kv_heads * (comp_dec * block_time_s(spec)
-                                 + skipped * SKIP_OVERHEAD_S)
+        t = spec.num_kv_heads * comp_dec * block_time_s(spec)
         for chunk, ctx in chunks:
             t += prefill_chunk_attn_time_s(int(chunk), int(ctx), spec)
         return t

@@ -9,12 +9,11 @@ and launch overhead — exactly the double cost ROADMAP item 2 targets.
 of a mixed iteration into a single scalar-prefetched work list: a
 *segment* is either a decode row (qlen = 1, ``tag = 0``) or a prefill
 chunk (qlen = chunk, ``tag = 1``), interleaved freely. One grid
-``(Hkv, W)`` where ``W >= Σ_s ceil((ctx_s + seg_s)/BS)`` is the caller's
-static work bucket. The engine picks ``W = pow2(decode items) +
-pow2(chunk items)`` — split buckets, because a single pow2 of the sum
-can overshoot the pair (9+8 → 32 vs 16+8) and make the merged grid pad
-MORE than the two kernels it replaces; split, the padding tail matches
-the separate launches exactly and fusion's win is the saved launch.
+``(Hkv, n)`` where ``n = Σ_s ceil((ctx_s + seg_s)/BS)`` is computed on
+the device from the call's lengths: a dynamic grid bound, so the kernel
+steps through the real items only and one compiled program serves every
+batch make-up of the same shapes. The work list itself keeps a static
+capacity from the shapes alone, ``B·NBT`` (the exact worst case).
 
 Work-list layout (DESIGN.md §Fused mixed-iteration attention): segment
 ``s`` contributes ``ceil(total_s/BS)`` consecutive items where
@@ -22,10 +21,10 @@ Work-list layout (DESIGN.md §Fused mixed-iteration attention): segment
 total = L — a decode row IS a chunk of length 1). Tag encoding is a
 prefetched int32 vector indexed by segment: 0 → narrow [G, BS] update on
 the q tile's first chunk row, 1 → full [C·G, BS] causally-masked update.
-The same garbage-block/sentinel discipline as the other flat grids
-applies: padding items alias the last real segment with block index NBT,
-the ``start < total`` guard skips them, and the final write is an
-idempotent re-write of that segment's row.
+Segments with total 0 (dead slots) contribute no items. A call with no
+items still launches one grid step: the capacity tail's padding item
+aliases the last segment with block index NBT, so the ``start < total``
+guard skips its compute and it writes that dead segment's row only.
 
 Quantized KV (``k_scale``/``v_scale`` given): the pool is int8 with f32
 per-(block, kv-head, position) scales; blocks are dequantized in-register
@@ -35,7 +34,6 @@ inside the shared flash core, so HBM DMA moves ~half the bytes
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,10 +41,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ops import (_flash_block_update, _flash_finish,
-                               _flash_init, flat_work_list)
+                               _flash_init, flat_work_list, work_blocks)
+
+# a work item packs (segment, logical block) into one int32
+_SEG_SHIFT, _BLK_MASK = 16, 0xFFFF
+
+# The work list and the block table sit in SMEM, B·NBT int32 entries each;
+# a v5e's 1 MiB holds both up to B·NBT = 129,024 (63 x 2,048) and refuses
+# 131,072. This limit keeps 16 KiB of room; callers size B and NBT under it.
+MAX_WORK_ITEMS = 2 ** 17 - 2 ** 12
 
 
-def _mixed_kernel(wreq_ref, wblk_ref,     # scalar prefetch [W], [W]
+def _mixed_kernel(work_ref,               # scalar prefetch [B·NBT]
                   tags_ref,               # scalar prefetch [B]
                   ctx_ref, slen_ref,      # scalar prefetch [B], [B]
                   bt_ref,                 # scalar prefetch [B, NBT]
@@ -54,12 +60,12 @@ def _mixed_kernel(wreq_ref, wblk_ref,     # scalar prefetch [W], [W]
                   k_ref, v_ref,           # [1, 1, BS, Dp] (one phys block)
                   *rest,                  # (+ks,vs if quantized) o, scratch
                   block_s: int, group: int, quantized: bool):
-    """Grid step (h, w): flat work item ``w`` = (segment ``wreq[w]``,
-    logical KV block ``wblk[w]``) against kv head ``h`` of ONE physical
-    pool block. Segment boundaries re-init the accumulators / write the
-    output row exactly like ``_flat_paged_kernel``; the per-segment tag
-    picks the decode or chunk compute shape against the SAME scratch and
-    KV DMA."""
+    """Grid step (h, w), ``w < n``: flat work item ``w`` = (segment
+    ``work[w] >> 16``, logical KV block ``work[w] & 0xFFFF``) against kv
+    head ``h`` of ONE physical pool block. Segment boundaries re-init the
+    accumulators / write the output row exactly like
+    ``_flat_paged_kernel``; the per-segment tag picks the decode or chunk
+    compute shape against the SAME scratch and KV DMA."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -68,10 +74,10 @@ def _mixed_kernel(wreq_ref, wblk_ref,     # scalar prefetch [W], [W]
     h = pl.program_id(0)
     w = pl.program_id(1)
     nw = pl.num_programs(1)
-    s = wreq_ref[w]
-    j = wblk_ref[w]
-    prev_s = wreq_ref[jnp.maximum(w - 1, 0)]
-    next_s = wreq_ref[jnp.minimum(w + 1, nw - 1)]
+    s = work_ref[w] >> _SEG_SHIFT
+    j = work_ref[w] & _BLK_MASK
+    prev_s = work_ref[jnp.maximum(w - 1, 0)] >> _SEG_SHIFT
+    next_s = work_ref[jnp.minimum(w + 1, nw - 1)] >> _SEG_SHIFT
     first = (w == 0) | (prev_s != s)
     last = (w == nw - 1) | (next_s != s)
 
@@ -121,10 +127,9 @@ def _mixed_kernel(wreq_ref, wblk_ref,     # scalar prefetch [W], [W]
     pl.when(last)(lambda: _flash_finish(o_ref, l_ref, acc_ref))
 
 
-@functools.partial(jax.jit, static_argnames=("num_work", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_mixed_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                           seg_lens, tags, k_scale=None, v_scale=None, *,
-                          num_work: Optional[int] = None,
                           interpret: bool = False):
     """Fused mixed-iteration attention over a paged KV pool.
 
@@ -146,12 +151,17 @@ def paged_mixed_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     tags         [B] int32         — 0 = decode row, 1 = prefill chunk
     k/v_scale    [NB, Hkv, BS] f32 — per-(block, kv-head, position) int8
                                      dequant scales (both or neither)
-    returns      [B, C, H, Dh]
+    returns      [B, C, H, Dh]     — rows of segments with total 0 are
+                                     garbage
 
-    Grid ``(Hkv, num_work)`` over the flat (segment, logical-block) work
-    list of Σ_s ceil((ctx_s + seg_s)/BS) real items — ONE launch covers
-    the whole mixed iteration. ``num_work`` is a static bucket (callers
-    round to a power of two; None = the worst case B·NBT).
+    Grid ``(Hkv, n)`` over the flat (segment, logical-block) work list,
+    ``n = Σ_s ceil((ctx_s + seg_s)/BS)`` real items counted on the device
+    (at least one step) — ONE launch covers the whole mixed iteration,
+    and calls that differ only in their lengths share one program. The
+    prefetched work list holds ``B·NBT`` entries, the most ``n`` can be,
+    one int32 each (segment in the high 16 bits, block in the low 16): it
+    shares the 1 MiB of SMEM with the ``[B, NBT]`` table, so ``B·NBT``
+    may not pass ``MAX_WORK_ITEMS``.
 
     TPU tiling (DESIGN.md §Block pool layout): a KV block is the
     contiguous [BS, Dp] slab of one kv head, and the q/o tile is
@@ -166,33 +176,39 @@ def paged_mixed_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     assert H % Hkv == 0, (H, Hkv)
     assert (k_scale is None) == (v_scale is None)
     quantized = k_scale is not None
-    W = num_work if num_work is not None else B * NBT
-    assert W >= 1
+    assert B < 2 ** 15 and NBT <= _BLK_MASK, (B, NBT)
+    assert B * NBT <= MAX_WORK_ITEMS, \
+        f"{B} segments x {NBT} table columns overflow the work list's SMEM"
     qg = q.reshape(B, C, Hkv, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, C * G, Dh)
     totals = (ctx_lens + seg_lens).astype(jnp.int32)
-    work_req, work_blk = flat_work_list(totals, NBT, BS, W)
+    work_req, work_blk = flat_work_list(totals, NBT, BS, B * NBT)
+    work = (work_req << _SEG_SHIFT) | work_blk
+    # the grid bound is the real item count, a traced scalar; a call with
+    # no items still runs one step, which the total guard skips
+    n = jnp.maximum(jnp.sum(work_blocks(totals, BS)), 1)
 
-    grid = (Hkv, W)
+    grid = (Hkv, n)
     kernel = functools.partial(_mixed_kernel, block_s=BS, group=G,
                                quantized=quantized)
 
-    def q_map(h, w, wreq, wblk, tags, ctx, slen, bt):
-        del wblk, tags, ctx, slen, bt
-        return (wreq[w], h, 0, 0)
+    def q_map(h, w, work, tags, ctx, slen, bt):
+        del tags, ctx, slen, bt
+        return (work[w] >> _SEG_SHIFT, h, 0, 0)
 
-    def _blk(w, wreq, wblk, bt):
-        # padding items carry block index NBT; clamp for the table lookup —
-        # whatever block it DMAs is skipped by the kernel's total guard
-        return bt[wreq[w], jnp.minimum(wblk[w], NBT - 1)]
+    def _blk(w, work, bt):
+        # the padding item of an empty call carries block index NBT; clamp
+        # for the table lookup — the kernel's total guard skips its block
+        return bt[work[w] >> _SEG_SHIFT,
+                  jnp.minimum(work[w] & _BLK_MASK, NBT - 1)]
 
-    def kv_map(h, w, wreq, wblk, tags, ctx, slen, bt):
+    def kv_map(h, w, work, tags, ctx, slen, bt):
         del tags, ctx, slen
-        return (_blk(w, wreq, wblk, bt), h, 0, 0)
+        return (_blk(w, work, bt), h, 0, 0)
 
-    def scale_map(h, w, wreq, wblk, tags, ctx, slen, bt):
+    def scale_map(h, w, work, tags, ctx, slen, bt):
         del h, tags, ctx, slen
-        return (_blk(w, wreq, wblk, bt), 0, 0)
+        return (_blk(w, work, bt), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, C * G, Dh), q_map),
@@ -208,7 +224,7 @@ def paged_mixed_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=5,
             grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, 1, C * G, Dh), q_map),
@@ -221,7 +237,7 @@ def paged_mixed_attention(q, k_pool, v_pool, block_tables, ctx_lens,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, C * G, Dh), q.dtype),
         name="paged_mixed_attention",
         interpret=interpret,
-    )(work_req, work_blk, tags.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+    )(work, tags.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       seg_lens.astype(jnp.int32), block_tables, *operands)
     return out.reshape(B, Hkv, C, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
         B, C, H, Dh)

@@ -83,6 +83,12 @@ def _flash_finish(o_ref, l_ref, acc_ref):
     o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
+def work_blocks(lengths, block_s: int):
+    """Work items per request of the flattened grids: ``ceil(L_b/BS)``
+    blocks each, 0 for an empty (dead) request. int32 ``[B]``."""
+    return jnp.maximum(-(-lengths // block_s), 0).astype(jnp.int32)
+
+
 def flat_work_list(lengths, nbt: int, block_s: int, num_work: int):
     """Flat (request, logical block) work list for the flattened grids —
     pure jnp, so the serving engine builds it on device every step.
@@ -91,10 +97,13 @@ def flat_work_list(lengths, nbt: int, block_s: int, num_work: int):
     (request-major, blocks in order); the tail up to ``num_work`` is
     padding aliasing the last request with ``nbt`` (one past the table) as
     its block index, which the kernels' ``start < length`` guard always
-    skips. Caller guarantees ``num_work >= Σ_b ceil(L_b/BS)``.
+    skips. ``num_work`` is the list's static length: the flat decode and
+    prefill kernels step through all of it (a bucket the caller picks,
+    ``>= Σ_b ceil(L_b/BS)``); the fused mixed kernel passes the worst
+    case ``B·NBT`` and steps through the real items only.
     Returns int32 ``(work_req [num_work], work_blk [num_work])``."""
     B = lengths.shape[0]
-    nb = jnp.maximum(-(-lengths // block_s), 0).astype(jnp.int32)
+    nb = work_blocks(lengths, block_s)
     offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
     total = offs[-1]
     w = jnp.arange(num_work, dtype=jnp.int32)

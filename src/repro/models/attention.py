@@ -508,7 +508,9 @@ def attention_decode_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
     HBM→VMEM by table indirection and never materialize the old
     ``[B, NBT·BS, Hkv, Dh]`` per-layer gather; "flat" additionally
     flattens the grid to ``attn_num_work`` (>= Σ_b ceil(L_b/BS)) work
-    items so short requests stop paying the batch-max block count.
+    items so short requests stop paying the batch-max block count;
+    "fused" runs the mixed kernel over the real items, counted on the
+    device, and ignores ``attn_num_work``.
     """
     assert not cfg.sliding_window, "paged decode is full-attention only"
     B = x.shape[0]
@@ -537,7 +539,7 @@ def attention_decode_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
         o = paged_mixed_attention(
             q, new_pool.k, new_pool.v, block_tables, pos,
             jnp.ones_like(pos), jnp.zeros_like(pos), ks, vs,
-            num_work=attn_num_work, interpret=attn_interpret)
+            interpret=attn_interpret)
         out = o.astype(q.dtype)                  # [B, 1, H, Dh]
     elif attn_backend != "dense":
         # Pallas path: the pool stays put; the kernel chases the block
@@ -637,8 +639,7 @@ def attention_prefill_chunk_paged(p, cfg: ModelConfig, x, pool_l: KVCache,
 def attention_mixed_paged(p, cfg: ModelConfig, x_dec, x_ck, pool_l,
                           bt_dec, bt_ck, pos, ctx_len, chunk_len, *,
                           attn_backend: str = "fused",
-                          attn_interpret: bool = False,
-                          attn_num_work: Optional[int] = None):
+                          attn_interpret: bool = False):
     """ONE fused attention launch for a whole mixed iteration: the decode
     batch advances one token while prompt chunks prefill beside it
     (DESIGN.md §Fused mixed-iteration attention).
@@ -699,7 +700,7 @@ def attention_mixed_paged(p, cfg: ModelConfig, x_dec, x_ck, pool_l,
         ks, vs = _pool_scales(new_pool)
         o = paged_mixed_attention(
             q_all, new_pool.k, new_pool.v, bt_all, ctx_all, slen_all, tags,
-            ks, vs, num_work=attn_num_work, interpret=attn_interpret)
+            ks, vs, interpret=attn_interpret)
         o = o.astype(qd.dtype)
         out_d, out_c = o[:Bd, :1], o[Bd:]
     else:

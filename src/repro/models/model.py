@@ -61,8 +61,7 @@ class Model:
     # the decode batch and the prefill chunks of one engine step through
     # the stack with ONE attention launch per layer.
     #   mixed_step(params, pool, dec_token, ck_tokens, bt_dec, bt_ck, pos,
-    #              ctx_len, chunk_len, *, attn_backend, attn_interpret,
-    #              attn_num_work)
+    #              ctx_len, chunk_len, *, attn_backend, attn_interpret)
     #       -> (dec_logits [Bd, V], ck_logits [Bp, V], new pool)
     mixed_step: Optional[Callable[..., Any]] = None
 
@@ -157,11 +156,11 @@ def _decoder_model(cfg: ModelConfig) -> Model:
 
     def mixed_step(params, pool, dec_token, ck_tokens, bt_dec, bt_ck, pos,
                    ctx_len, chunk_len, *, attn_backend: str = "fused",
-                   attn_interpret: bool = False, attn_num_work=None):
+                   attn_interpret: bool = False):
         return transformer.forward_mixed(
             params, cfg, dec_token, ck_tokens, pool, bt_dec, bt_ck, pos,
             ctx_len, chunk_len, attn_backend=attn_backend,
-            attn_interpret=attn_interpret, attn_num_work=attn_num_work)
+            attn_interpret=attn_interpret)
 
     return Model(cfg, init, loss, prefill, decode_step, init_cache,
                  init_paged_cache=init_paged_cache,

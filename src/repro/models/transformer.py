@@ -289,13 +289,12 @@ def forward_prefill_chunk(p, cfg: ModelConfig, tokens, pool: KVCache,
 
 def block_mixed(pl, cfg: ModelConfig, x_dec, x_ck, pool_l, bt_dec, bt_ck,
                 pos, ctx_len, chunk_len, attn_backend: str = "fused",
-                attn_interpret: bool = False, attn_num_work=None):
+                attn_interpret: bool = False):
     hd = rms_norm(x_dec, pl["ln_attn"], cfg.norm_eps)
     hc = rms_norm(x_ck, pl["ln_attn"], cfg.norm_eps)
     ad, ac, new_pool = attn.attention_mixed_paged(
         pl["attn"], cfg, hd, hc, pool_l, bt_dec, bt_ck, pos, ctx_len,
-        chunk_len, attn_backend=attn_backend, attn_interpret=attn_interpret,
-        attn_num_work=attn_num_work)
+        chunk_len, attn_backend=attn_backend, attn_interpret=attn_interpret)
     x_dec = x_dec + ad
     x_ck = x_ck + ac
     md, aux_d = _mlp_part(pl, cfg, x_dec)
@@ -305,8 +304,7 @@ def block_mixed(pl, cfg: ModelConfig, x_dec, x_ck, pool_l, bt_dec, bt_ck,
 
 def forward_mixed(p, cfg: ModelConfig, dec_token, ck_tokens, pool,
                   bt_dec, bt_ck, pos, ctx_len, chunk_len, *,
-                  attn_backend: str = "fused", attn_interpret: bool = False,
-                  attn_num_work=None):
+                  attn_backend: str = "fused", attn_interpret: bool = False):
     """One whole MIXED iteration through the stack: the decode batch
     (``dec_token [Bd]``, ``pos [Bd]``, -1 = dead slot) advances one token
     while prompt chunks (``ck_tokens [Bp, C]``, ``ctx_len``/``chunk_len``)
@@ -327,7 +325,7 @@ def forward_mixed(p, cfg: ModelConfig, dec_token, ck_tokens, pool,
         x_dec, x_ck, new_pool_l, _ = block_mixed(
             pl_, cfg, x_dec, x_ck, pool_l, bt_dec, bt_ck, pos, ctx_len,
             chunk_len, attn_backend=attn_backend,
-            attn_interpret=attn_interpret, attn_num_work=attn_num_work)
+            attn_interpret=attn_interpret)
         return (x_dec, x_ck), new_pool_l
 
     (x_dec, x_ck), new_pool = jax.lax.scan(body, (x_dec, x_ck),

@@ -62,6 +62,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.migration import (gather_kv_blocks, kv_bytes,
                                   scatter_kv_blocks)
 from repro.kernels.cost import pow2_bucket
+from repro.kernels.mixed_attention import MAX_WORK_ITEMS
 from repro.launch.mesh import make_tp_mesh
 from repro.launch.shardings import (piece_spec_tree, pool_spec_tree,
                                     serving_param_spec_tree)
@@ -253,7 +254,6 @@ class Engine:
                 self._dev_len = jnp.zeros((max_slots,), jnp.int32)
                 self._dev_tok = jnp.zeros((max_slots,), jnp.int32)
                 self._burst_fns: Dict[Tuple[int, int], Callable] = {}
-                self._mixed_fns: Dict[int, Callable] = {}
                 if self.tp > 1:
                     # bucketed prefill returns a contiguous KV piece
                     # [L, B, P, Hkv, Dh] — kv heads sharded like the pool
@@ -309,6 +309,28 @@ class Engine:
             self.chunked_prefill and self.device_resident
             and self.attn_backend == "fused"
             and getattr(model, "mixed_step", None) is not None)
+        if self.fused_mixed:
+            self._mixed = self._mixed_fn()
+        # Chunks a step plans at most. On "fused" the kernel holds (decode
+        # rows + chunks) x table width work items in SMEM; a chunk's table
+        # covers its padded rows, at most one chunk bucket past max_seq,
+        # so the widest call caps the chunks.
+        self._max_chunks = max_slots
+        if self.paged:
+            self._max_chunk_nbt = blocks_for(
+                max_seq + _next_pow2(self.prefill_token_budget),
+                self.block_size)
+        if self.paged and self.attn_backend == "fused":
+            nbt = (self._max_chunk_nbt if self.chunked_prefill
+                   else blocks_for(max_seq, self.block_size))
+            fit = MAX_WORK_ITEMS // nbt - max_slots
+            if fit < int(self.chunked_prefill):
+                raise ValueError(
+                    f"fused attention: {max_slots} slots x {nbt} table "
+                    f"columns leave no room for a prefill chunk in the "
+                    f"kernel's {MAX_WORK_ITEMS}-item work list; lower "
+                    f"max_slots or max_seq")
+            self._max_chunks = min(max_slots, fit)
         # Refcounted prefix cache (DESIGN.md §Prefix cache): admission
         # shares already-resident full prompt blocks and starts chunked
         # prefill at ctx_done = cached_tokens, so a warm request skips the
@@ -361,8 +383,9 @@ class Engine:
         self.promoted_blocks_total = 0
         # work-list accounting of the device loop's launches (the
         # ``engine.launch`` span's ``items``/``real_items``, summed):
-        # work items launched (the pow2 bucket the flat/fused kernels run,
-        # x the horizon) and those holding a real KV block
+        # work items launched (the fused kernel's real items; the other
+        # backends' pow2 bucket, x the horizon) and those holding a real
+        # KV block
         self.work_items = 0
         self.real_work_items = 0
         # first admissions of requests a server submitted, and the sum of
@@ -880,13 +903,14 @@ class Engine:
         budget = self.prefill_token_budget
         plan: List[Tuple[int, int]] = []            # (slot, chunk_len)
         for slot in list(self._prefill_order):      # oldest admitted first
-            if budget <= 0:
+            if budget <= 0 or len(plan) == self._max_chunks:
                 break
             req = self.slots[slot]
             clen = min(req.prefill_target_len - req.ctx_done, budget)
             plan.append((slot, clen))
             budget -= clen
-        while self.waiting and budget > 0:
+        while (self.waiting and budget > 0
+               and len(plan) < self._max_chunks):
             req = self.waiting[0]
             if len(req.prompt) + 1 > self.max_seq:  # can NEVER fit: fail
                 self.waiting.popleft()
@@ -939,8 +963,9 @@ class Engine:
         """Device arrays for ALL of the step's planned chunks — the prompt
         half of the mixed iteration, consumed either by the separate
         ``prefill_chunk`` call or by the fused mixed call. Chunks are
-        padded to a common pow2 bucket and a common pow2 table width
-        (compiles stay O(slots · log budget · log max_seq)); each chunk's
+        padded to a common pow2 bucket and a common pow2 table width, the
+        latter capped at the widest a chunk can need (compiles stay
+        O(slots · log budget · log max_seq)); each chunk's
         blocks are allocated here, always covered by its admission
         reservation, so allocation cannot fail. Table tails are the
         garbage block, so the padding rows of short chunks never touch
@@ -958,7 +983,8 @@ class Engine:
             nbt = max(nbt, blocks_for(req.ctx_done + C, self.block_size))
             self.prefill_work_blocks += need    # grid-step mirror
             self.prefill_tokens_done += clen
-        nbt = _next_pow2(nbt)
+        assert nbt <= self._max_chunk_nbt, (nbt, self._max_chunk_nbt)
+        nbt = min(_next_pow2(nbt), self._max_chunk_nbt)
         toks = np.zeros((B, C), np.int32)
         bt = np.full((B, nbt), self.garbage_block, np.int32)
         ctxs = np.zeros((B,), np.int32)
@@ -1319,8 +1345,9 @@ class Engine:
     # ---- device-resident step (paged default) --------------------------------
     def _burst_fn(self, num_work: int, horizon: int):
         """Jitted ``horizon``-iteration decode micro-batch, cached per
-        (num_work, horizon) — both pow2-bucketed, so the cache stays
-        O(log² ·). Shape changes (table width growth) retrace via jit."""
+        (num_work, horizon) — both pow2-bucketed, and ``num_work`` is 0
+        but on the "flat" backend, so the cache stays O(log² ·). Shape
+        changes (table width growth) retrace via jit."""
         key = (num_work, horizon)
         fn = self._burst_fns.get(key)
         if fn is not None:
@@ -1356,20 +1383,17 @@ class Engine:
         self._burst_fns[key] = fn
         return fn
 
-    def _mixed_fn(self, num_work: int):
+    def _mixed_fn(self):
         """Jitted FUSED mixed iteration: the whole decode batch and the
         step's prompt chunks advance through the stack in this single
         attention-bearing call — one tagged work-list kernel launch per
-        layer (DESIGN.md §Fused mixed-iteration attention). Cached per
-        pow2 ``num_work``; shape changes (table width, chunk bucket,
-        chunk count) retrace via jit."""
-        fn = self._mixed_fns.get(num_work)
-        if fn is not None:
-            return fn
+        layer (DESIGN.md §Fused mixed-iteration attention). One function
+        per engine: the kernel counts its work items on the device, so
+        only shape changes (table width, chunk bucket, chunk count)
+        retrace via jit."""
         mixed = functools.partial(self.model.mixed_step,
                                   attn_backend=self.attn_backend,
-                                  attn_interpret=self.attn_interpret,
-                                  attn_num_work=num_work)
+                                  attn_interpret=self.attn_interpret)
 
         def step(params, cache, bt, tok, length, ck_tokens, bt_ck, ctx, clen):
             live = length > 0
@@ -1386,9 +1410,7 @@ class Engine:
             step = self._smap(step, (self._pspec, self._pool_spec,
                                      P(), P(), P(), P(), P(), P(), P()),
                               (self._pool_spec, P(), P(), P(), P()))
-        fn = jax.jit(step)
-        self._mixed_fns[num_work] = fn
-        return fn
+        return jax.jit(step)
 
     def _grow_tables(self, live, h: int) -> int:
         """Pre-grow the live rows' block tables to cover every write of an
@@ -1471,21 +1493,18 @@ class Engine:
                                         self.block_size)
                              for s, c in chunk_plan]
                 real = sum(dec_blocks) + sum(ck_blocks)
-                # bucket = pow2(decode items) + pow2(chunk items), NOT
-                # pow2(sum): the padding tail then never exceeds what the
-                # two separate kernels would pad (pow2(a+b) can overshoot
-                # pow2(a)+pow2(b)), so fusing strictly saves the launch;
-                # the jit cache stays O(log²) keys
-                num_work = ((_next_pow2(sum(dec_blocks)) if live else 0)
-                            + _next_pow2(sum(ck_blocks)))
-                self.work_items += num_work
+                # the kernel runs exactly the real items, counted on the
+                # device, over a work list as long as its segments x
+                # their common table width
+                self.work_items += real
                 self.real_work_items += real
                 if _span.is_enabled():
-                    sp.set_metadata(horizon=h, items=num_work,
-                                    real_items=real)
-                fn = self._mixed_fn(num_work)
+                    sp.set_metadata(
+                        horizon=h, items=real, real_items=real,
+                        capacity=(self.max_slots + len(chunk_plan))
+                        * max(self._nbt_cap, bt_ck.shape[1]))
                 (self.cache, self._dev_tok, self._dev_len, new_tok,
-                 ck_tok) = attn_call(fn, self.params, self.cache,
+                 ck_tok) = attn_call(self._mixed, self.params, self.cache,
                                      self._dev_bt, self._dev_tok,
                                      self._dev_len, ck_toks, bt_ck, ctxs,
                                      clens)
@@ -1529,23 +1548,28 @@ class Engine:
                     sp.set_metadata(rows=rows)
             with _span("engine.launch", eng=self.id, kind="burst") as sp:
                 lens = [int(self.slot_len[i]) for i, _ in live]
-                # the work list covers the burst's longest contexts;
                 # iteration s attends over slot_len + s rows
-                items = _next_pow2(sum(blocks_for(n + h - 1, self.block_size)
-                                       for n in lens))
                 real = sum(blocks_for(n + s, self.block_size)
                            for s in range(h) for n in lens)
-                self.work_items += items * h
+                # the fused kernel runs the real items, counted on the
+                # device, over a max_slots x table-width work list; the
+                # others a static bucket covering the burst's longest
+                # contexts
+                fused = self.attn_backend == "fused"
+                items = real if fused else h * _next_pow2(sum(
+                    blocks_for(n + h - 1, self.block_size) for n in lens))
+                self.work_items += items
                 self.real_work_items += real
                 if _span.is_enabled():
-                    sp.set_metadata(horizon=h, items=items * h,
-                                    real_items=real)
-                # num_work only shapes the flat-work-list grids
-                # (flat/fused); for the other backends key the jit cache on
-                # a single value so pow2 growth of the live block count
-                # never forces a recompile
-                num_work = (items if self.attn_backend in ("flat", "fused")
-                            else 0)
+                    sp.set_metadata(
+                        horizon=h, items=items, real_items=real,
+                        capacity=(self.max_slots * self._nbt_cap * h
+                                  if fused else items))
+                # num_work only shapes the flat grid's static work list;
+                # for the other backends key the jit cache on a single
+                # value so growth of the live block count never forces a
+                # recompile
+                num_work = items // h if self.attn_backend == "flat" else 0
                 fn = self._burst_fn(num_work, h)
                 self.cache, self._dev_tok, self._dev_len, toks = attn_call(
                     fn, self.params, self.cache, self._dev_bt, self._dev_tok,

@@ -2,9 +2,10 @@
 §Fused mixed-iteration attention, §Quantized KV blocks): the one-launch
 kernel vs. the two-kernel reference and the dense oracle on mixed batches
 at 128x length spread — dead slots, aliased prefix blocks, interleaved
-tags — int8 bounded error, engine greedy bit-parity, the one-attention-
-call and one-d2h-per-mixed-step contracts, and the split-pow2 cost
-mirror."""
+tags — the device-counted grid (exact item counts, an empty call, one
+program across counts), int8 bounded error, engine greedy bit-parity,
+the one-attention-call and one-d2h-per-mixed-step contracts, one mixed
+program across batch make-ups, and the cost mirror."""
 import math
 
 import jax
@@ -15,7 +16,7 @@ import pytest
 import repro.serving.engine as engine_mod
 from repro.configs import get_config
 from repro.kernels.cost import (AttnSpec, LAUNCH_OVERHEAD_S,
-                                fused_grid_items, kv_bytes_per_elem,
+                                SKIP_OVERHEAD_S, kv_bytes_per_elem,
                                 mixed_iter_time_s, pow2_bucket)
 from repro.kernels.decode_attention import paged_decode_attention_flat
 from repro.kernels.mixed_attention import paged_mixed_attention
@@ -25,6 +26,7 @@ from repro.models import build_model
 from repro.models.attention import (KVCache, dequantize_piece,
                                     quantize_kv, quantize_piece,
                                     resolve_paged_backend)
+from repro.serving.block_pool import blocks_for
 from repro.serving.engine import Engine
 from repro.serving.request import ServeRequest
 
@@ -34,16 +36,18 @@ RNG = np.random.default_rng(23)
 # --------------------------------------------------------------------------
 # Kernel: fused work list vs. the two kernels it replaces
 # --------------------------------------------------------------------------
-def _mixed_case(segs, C, H, Hkv, Dh, BS, dtype, alias=None):
+def _mixed_case(segs, C, H, Hkv, Dh, BS, dtype, alias=None, nbt=None):
     """Build one mixed iteration. ``segs``: ``("dec", L)`` is a decode row
     whose cache holds L tokens (ctx = L-1, seg = 1; L = 0 is a dead slot
     contributing zero work items) and ``("ck", ctx, clen)`` a prefill
     chunk. ``alias=(i, j, nb)`` makes segments i and j share their first
-    ``nb`` physical blocks (prefix-cache aliasing). Returns the fused
-    operands plus each segment's contiguous K/V for the oracle."""
+    ``nb`` physical blocks (prefix-cache aliasing); ``nbt`` sets the
+    table width (default: one column past the longest segment). Returns
+    the fused operands plus each segment's contiguous K/V for the
+    oracle."""
     B = len(segs)
     totals = [(s[1] if s[0] == "dec" else s[1] + s[2]) for s in segs]
-    NBT = max(max(-(-t // BS) for t in totals), 1) + 1
+    NBT = nbt or max(max(-(-t // BS) for t in totals), 1) + 1
     NB = sum(-(-t // BS) for t in totals) + 3
     perm = RNG.permutation(NB)
     k_pool = np.zeros((NB, Hkv, BS, Dh), np.float32)  # head-major pool
@@ -87,7 +91,7 @@ ALIAS = (5, 6, 1)          # segs 5 and 6 share physical block 0
                                        (jnp.bfloat16, 2e-2)])
 def test_fused_matches_two_kernel_paths(dtype, tol):
     """One fused launch == the decode-flat + prefill-chunk pair it
-    replaces, on the SAME pool, for real/pow2/worst-case work buckets."""
+    replaces, on the SAME pool."""
     C, BS = 32, 16
     q, kp, vp, bt, ctx, seg, tags, full = _mixed_case(
         SEGS, C, 8, 2, 64, BS, dtype, alias=ALIAS)
@@ -99,21 +103,106 @@ def test_fused_matches_two_kernel_paths(dtype, tol):
         q[dec, 0], kp, vp, bt[dec, :], lens, interpret=True)
     ref_ck = paged_prefill_attention(
         q[ck, :], kp, vp, bt[ck, :], ctx[ck], seg[ck], interpret=True)
-    real = sum(math.ceil((int(ctx[i]) + int(seg[i])) / BS)
-               for i in range(len(SEGS)))
-    for W in (real, pow2_bucket(real), None):
+    out = np.asarray(paged_mixed_attention(
+        q, kp, vp, bt, ctx, seg, tags, interpret=True), np.float32)
+    for r, i in enumerate(dec):
+        np.testing.assert_allclose(
+            out[i, 0], np.asarray(ref_dec, np.float32)[r],
+            atol=tol, rtol=tol)
+    for r, i in enumerate(ck):
+        cl = int(seg[i])
+        np.testing.assert_allclose(
+            out[i, :cl], np.asarray(ref_ck, np.float32)[r, :cl],
+            atol=tol, rtol=tol)
+
+
+def _segment_oracle(q, full, segs):
+    """Plain float64 attention of each live segment's query rows over its
+    own contiguous K/V: row r of a segment with context ``ctx`` sits at
+    position ctx + r and sees keys 0..ctx + r. {segment: [rows, H, Dh]}."""
+    q = np.asarray(q, np.float64)
+    H, Dh = q.shape[2], q.shape[3]
+    out = {}
+    for i, s in enumerate(segs):
+        ctx, n = (s[1] - 1, 1) if s[0] == "dec" else (s[1], s[2])
+        if ctx + n == 0:
+            continue                             # dead segment: no rows
+        k, v = (np.asarray(a, np.float64) for a in full[i])
+        G = H // k.shape[1]
+        rows = np.zeros((n, H, Dh))
+        for r in range(n):
+            for hq in range(H):
+                sc = k[:ctx + r + 1, hq // G] @ q[i, r, hq] / math.sqrt(Dh)
+                p = np.exp(sc - sc.max())
+                rows[r, hq] = p @ v[:ctx + r + 1, hq // G] / p.sum()
+        out[i] = rows
+    return out
+
+
+# the device-counted grid: mixed tags with a dead segment and a shared
+# prefix block; one real item among dead slots; no item at all; and every
+# segment filling its table, so the count equals the work-list capacity
+COUNT_CASES = {
+    "mixed": (SEGS, ALIAS, None),
+    "single_item": ([("dec", 0), ("dec", 5), ("dec", 0)], None, None),
+    "empty": ([("dec", 0), ("ck", 0, 0), ("dec", 0)], None, None),
+    "at_capacity": ([("ck", 32, 16), ("dec", 48), ("ck", 40, 8)], None, 3),
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("case", sorted(COUNT_CASES))
+def test_fused_device_count_matches_oracle(case, kv_dtype):
+    """The kernel steps through Σ_s ceil((ctx_s + seg_s)/BS) items, counted
+    on the device, and every live row matches the plain attention of its
+    segment; int8 blocks within the quantized-KV contract (abs <= 0.05).
+    An empty call runs its one skipped step and writes zeros into the
+    last (dead) segment's row only."""
+    segs, alias, nbt = COUNT_CASES[case]
+    C, BS = 32, 16
+    q, kp, vp, bt, ctx, seg, tags, full = _mixed_case(
+        segs, C, 8, 2, 64, BS, jnp.float32, alias=alias, nbt=nbt)
+    count = sum(-(-int(c + n) // BS) for c, n in zip(ctx, seg))
+    B, NBT = bt.shape
+    assert (count == B * NBT) == (case == "at_capacity")
+    scales = ()
+    if kv_dtype == "int8":
+        (kp, ks), (vp, vs) = quantize_kv(kp), quantize_kv(vp)
+        scales = (ks, vs)
+    out = np.asarray(paged_mixed_attention(
+        q, kp, vp, bt, ctx, seg, tags, *scales, interpret=True), np.float64)
+    tol = 3e-5 if kv_dtype == "f32" else 0.05
+    want = _segment_oracle(q, full, segs)
+    assert (len(want) == 0) == (case == "empty")
+    for i, rows in want.items():
+        np.testing.assert_allclose(out[i, :len(rows)], rows, atol=tol,
+                                   rtol=tol)
+    if case == "empty":
+        assert not np.any(out[-1])
+
+
+def test_fused_one_program_across_counts():
+    """Two calls of the same shapes whose real item counts differ share
+    ONE compiled program: the count is a traced grid bound, not a static
+    argument."""
+    C, BS = 8, 16                      # shapes no other test uses
+    n0 = paged_mixed_attention._cache_size()
+    counts = []
+    for segs in ([("dec", 40), ("ck", 16, 8), ("dec", 3)],
+                 [("dec", 7), ("ck", 0, 5), ("dec", 0)]):
+        q, kp, vp, bt, ctx, seg, tags, full = _mixed_case(
+            segs, C, 6, 3, 32, BS, jnp.float32, nbt=4)
+        # one pool size for both calls
+        pad = ((0, 16 - kp.shape[0]), (0, 0), (0, 0), (0, 0))
+        kp, vp = jnp.pad(kp, pad), jnp.pad(vp, pad)
+        counts.append(sum(-(-int(c + n) // BS) for c, n in zip(ctx, seg)))
         out = np.asarray(paged_mixed_attention(
-            q, kp, vp, bt, ctx, seg, tags, num_work=W, interpret=True),
-            np.float32)
-        for r, i in enumerate(dec):
-            np.testing.assert_allclose(
-                out[i, 0], np.asarray(ref_dec, np.float32)[r],
-                atol=tol, rtol=tol)
-        for r, i in enumerate(ck):
-            cl = int(seg[i])
-            np.testing.assert_allclose(
-                out[i, :cl], np.asarray(ref_ck, np.float32)[r, :cl],
-                atol=tol, rtol=tol)
+            q, kp, vp, bt, ctx, seg, tags, interpret=True), np.float64)
+        for i, rows in _segment_oracle(q, full, segs).items():
+            np.testing.assert_allclose(out[i, :len(rows)], rows, atol=3e-5,
+                                       rtol=3e-5)
+    assert counts[0] != counts[1]
+    assert paged_mixed_attention._cache_size() == n0 + 1
 
 
 def test_fused_decode_rows_match_dense_oracle():
@@ -285,6 +374,111 @@ def test_fused_mixed_step_one_attn_call_one_sync(setup, rng, monkeypatch):
     assert all(s == 1 for s in sync_sep), sync_sep
 
 
+def _record_mixed_launches(monkeypatch):
+    """Stand in for the engine's profiler span; returns the list that
+    collects each mixed launch's stats."""
+    stats = []
+
+    class Span:
+        def __init__(self, name, **kw):
+            self.launch = name == "engine.launch" and kw["kind"] == "mixed"
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kw):
+            if self.launch:
+                stats.append(kw)
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+    monkeypatch.setattr(engine_mod, "_span", Span)
+    return stats
+
+
+def test_fused_engine_one_mixed_program_across_counts(setup, rng,
+                                                      monkeypatch):
+    """Mixed steps whose work counts differ, at one table width and one
+    chunk shape, run ONE compiled mixed program; the launched items are
+    the real ones (no padding), over a work list of (slots + chunks) x
+    table width."""
+    cfg, model, params = setup
+    stats = _record_mixed_launches(monkeypatch)
+    # max_seq = one block: every table is one column wide; 4-token prompts
+    # under a 4-token budget: one 4-row chunk a step, beside a decode
+    # batch that fills up and drains
+    eng = Engine(0, model, params, max_slots=4, max_seq=16, block_size=16,
+                 attn_backend="fused", prefill_token_budget=4)
+    assert eng.fused_mixed
+    reqs = [ServeRequest(i, rng.integers(0, cfg.vocab_size, 4)
+                         .astype(np.int32), 6) for i in range(7)]
+    for r in reqs:
+        eng.submit(r)
+    counts, done = set(), []
+    while len(done) < len(reqs):
+        m0, real0 = eng.mixed_steps, eng.real_work_items
+        done += eng.step()
+        if eng.mixed_steps > m0:
+            counts.add(eng.real_work_items - real0)
+    assert len(counts) >= 3, counts
+    assert eng._mixed._cache_size() == 1
+    assert eng.work_items == eng.real_work_items > 0
+    assert stats and all(s["items"] == s["real_items"] <= s["capacity"] == 5
+                         for s in stats), stats
+
+
+@pytest.mark.parametrize("limit", ["chip", "two_chunks", "no_room"])
+def test_fused_engine_chunks_fit_work_list_limit(setup, rng, monkeypatch,
+                                                 limit):
+    """The fused kernel holds (decode rows + chunks) x table width work
+    items in SMEM. Under the chip's limit a step plans a chunk for every
+    slot beside the decode rows (B = 2·max_slots); under a limit that
+    fits two chunks it plans two a step and serves the same greedy
+    tokens; where no chunk fits beside the slots, the engine refuses to
+    build."""
+    cfg, model, params = setup
+    # budget 16 -> chunk tables up to blocks_for(32 + 16) = 3 columns
+    kw = dict(max_slots=4, max_seq=32, block_size=16, attn_backend="fused",
+              prefill_token_budget=16)
+    prompts = [rng.integers(0, cfg.vocab_size, 3).astype(np.int32)
+               for _ in range(4)]
+    want = None
+    if limit != "chip":
+        want = _drain(Engine(0, model, params, **kw),
+                      [ServeRequest(i, p, 4) for i, p in enumerate(prompts)])
+        cap = {"two_chunks": (4 + 2) * 3, "no_room": 4 * 3}[limit]
+        monkeypatch.setattr(engine_mod, "MAX_WORK_ITEMS", cap)
+        if limit == "no_room":
+            with pytest.raises(ValueError, match="no room for a prefill"):
+                Engine(0, model, params, **kw)
+            return
+    stats = _record_mixed_launches(monkeypatch)
+    eng = Engine(0, model, params, **kw)
+    plan, chunks = eng._plan_chunks, []
+
+    def counted_plan():
+        rejected, p = plan()
+        chunks.append(len(p))
+        return rejected, p
+
+    eng._plan_chunks = counted_plan
+    got = _drain(eng, [ServeRequest(i, p, 4) for i, p in enumerate(prompts)])
+    chunks = [n for n in chunks if n]
+    if limit == "chip":
+        assert chunks == [4]                     # B = 2·max_slots
+    else:
+        assert chunks == [2, 2]
+        assert all(s["capacity"] <= engine_mod.MAX_WORK_ITEMS
+                   for s in stats), stats
+        assert ({r.req_id: r.generated for r in got}
+                == {r.req_id: r.generated for r in want})
+
+
 # --------------------------------------------------------------------------
 # Backend resolution + the split-pow2 cost mirror
 # --------------------------------------------------------------------------
@@ -297,25 +491,31 @@ def test_resolve_backend_fused_auto_on_tpu_dense_elsewhere():
 
 
 def test_cost_fused_split_buckets_and_launch_saving():
-    """fused_grid_items buckets decode and chunk halves separately —
-    pow2(9+8)=32 would overshoot 16+8 — so the fused analytic time is the
-    separate path minus EXACTLY the extra launch, for any shape."""
+    """The fused mirror prices the real decode and chunk items only (the
+    kernel counts them on the device), so against the separate kernels it
+    saves the extra launch PLUS both halves' pow2 padding tails, exactly,
+    for any shape."""
     BS = 16
-    dec = [16 * 9]                               # 9 blocks -> pow2 16
-    chunks = [(8 * BS, 0)]                       # 8 blocks -> pow2 8
-    assert fused_grid_items(chunks, dec, BS) == 16 + 8
     spec = AttnSpec(8, 2, 64, block_s=BS)
     for lens, cks in (([7, 32, 152, 700], [(64, 256)]),   # unlucky bucket
                       ([16 * 9], [(8 * 16, 0)]),
                       ([4, 512, 1], [(32, 100), (17, 48)])):
+        dec = sum(blocks_for(n, BS) for n in lens)
+        ck = sum(blocks_for(c + x, BS) for c, x in cks)
+        padding = pow2_bucket(dec) - dec + pow2_bucket(ck) - ck
         t_fused = mixed_iter_time_s(cks, lens, spec, decode_backend="fused")
         t_sep = mixed_iter_time_s(cks, lens, spec, decode_backend="flat")
         assert t_fused < t_sep
-        np.testing.assert_allclose(t_sep - t_fused, LAUNCH_OVERHEAD_S,
-                                   rtol=1e-9)
-    # no chunks -> no extra launch to save: fused == flat exactly
-    assert mixed_iter_time_s([], [64, 256], spec, decode_backend="fused") \
-        == mixed_iter_time_s([], [64, 256], spec, decode_backend="flat")
+        np.testing.assert_allclose(
+            t_sep - t_fused,
+            LAUNCH_OVERHEAD_S + spec.num_kv_heads * padding * SKIP_OVERHEAD_S,
+            rtol=1e-9)
+    # no chunks -> no launch to save: fused == flat less the decode tail
+    lens = [64, 256]                       # 20 blocks -> pow2 32
+    np.testing.assert_allclose(
+        mixed_iter_time_s([], lens, spec, decode_backend="flat")
+        - mixed_iter_time_s([], lens, spec, decode_backend="fused"),
+        spec.num_kv_heads * 12 * SKIP_OVERHEAD_S, rtol=1e-9)
 
 
 def test_cost_kv_bytes_per_elem():

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.decode_attention import paged_decode_attention_flat
-from repro.kernels.mixed_attention import paged_mixed_attention
+from repro.kernels.mixed_attention import (MAX_WORK_ITEMS,
+                                          paged_mixed_attention)
 from repro.kernels.prefill_attention import paged_prefill_attention
 from repro.models import build_model
 from repro.models.attention import pool_row_width
@@ -81,12 +82,32 @@ def test_mixed_kernel_compiles_for_v5e(one_chip, no_cache, width, kv_dtype):
             s((B,), jnp.int32), s((B,), jnp.int32)]
     if kv_dtype == "int8":
         args += [s((NB, Hkv, BS), jnp.float32)] * 2
-    fn = jax.jit(functools.partial(paged_mixed_attention, num_work=W))
-    compiled = fn.lower(*args).compile()
+    compiled = jax.jit(paged_mixed_attention).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # no relayout copy of the pool: the kernel reads it in place
     pool_bytes = NB * Hkv * BS * Dp * jnp.dtype(pool_dt).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+@pytest.mark.parametrize("Bq", [33, MAX_WORK_ITEMS // 2048])
+def test_mixed_kernel_work_list_fits_smem_at_qwen_stage(one_chip, no_cache,
+                                                        Bq):
+    """The qwen2.5-14b pipeline stage's mixed call: 32 decode rows plus a
+    chunk (B 33), 256-token chunks of 5 heads a kv head (C·G 1,280),
+    tables 2,048 blocks wide (32,768 tokens), 8 kv heads of 128; and the
+    most segments the engine lets such a call hold (B·NBT at
+    MAX_WORK_ITEMS). The grid bound is counted on the device, and the
+    scalar-prefetched work list holds B·NBT packed items beside the
+    [B, 2048] table: the compile refuses it if the two overflow the 1 MiB
+    of SMEM."""
+    Cq, H, Hkv, Dh, NBTq, NBq = 256, 40, 8, 128, 2048, 3073
+    s = functools.partial(_spec, one_chip)
+    pool = s((NBq, Hkv, BS, pool_row_width(Dh)), jnp.bfloat16)
+    compiled = jax.jit(paged_mixed_attention).lower(
+        s((Bq, Cq, H, Dh), jnp.bfloat16), pool, pool,
+        s((Bq, NBTq), jnp.int32), s((Bq,), jnp.int32), s((Bq,), jnp.int32),
+        s((Bq,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("kernel", ["flat_decode", "chunked_prefill"])
@@ -125,7 +146,7 @@ def test_single_layer_mixed_step_compiles_for_v5e(one_chip, no_cache):
     pool = place(jax.eval_shape(lambda: model.init_paged_cache(NB, BS)))
     Bd, Bp = 8, 2
     step = jax.jit(functools.partial(model.mixed_step, attn_backend="fused",
-                                     attn_interpret=False, attn_num_work=W),
+                                     attn_interpret=False),
                    donate_argnums=1)
     compiled = step.lower(
         params, pool, s((Bd,), jnp.int32), s((Bp, C), jnp.int32),
