@@ -3,10 +3,40 @@ closed-loop clients, measure a window, check the served tokens against the
 reference, and assemble the result line.
 
 Everything specific to a cell is found by name: ``configs/<config>.json``
-(model sizes and the server's engine options), ``traffic/<mix>.json``
+(model sizes and the server's engine options), ``arch/<architecture>.py``
+(named by the configuration's ``architecture`` key), ``traffic/<mix>.json``
 (the generator's parameters), ``limits/<cell>.json`` (the limits of the
 numbers compared) and ``metrics/<metric>.py`` (one reader per per-layer
-metric). Adding a cell, a mix or a metric adds files and edits none.
+metric). Adding a cell, a mix or a metric adds files and edits none;
+so does adding a configuration, of an architecture the benchmark has or
+of a new one, which brings ``arch/<name>.py`` beside its ``configs/``,
+``traffic/``, ``limits/`` and ``metrics/`` files.
+
+An architecture module gives:
+
+* ``dims(config) -> m``: the sizes, a dict of plain values (its
+  ``"dtype"`` the served dtype's name), from the configuration file;
+* ``program_config(name, m, max_position)``: the program's
+  ``ModelConfig`` for them;
+* ``make_params(m, seed, shardings=None)``: the served weights from the
+  seed, on the device in one jitted call, made straight into
+  ``shardings`` (a tree of shardings like the params) where given;
+* ``Reference(m, seed)``: a plain float32 forward with the weights made
+  again from the seed, importing nothing of the program;
+  ``.logits(tokens, start, fp8=False)`` gives the float32 logits at
+  positions ``start..`` of ``tokens``, and ``fp8=True`` its float8
+  control;
+* the arithmetic the metric readers call through ``ctx["flops"]``:
+  ``token_flops(m, ctx, logits)``, ``chunk_flops(m, ctx0, clen,
+  logits)`` and ``attn_least_time(m, decode_ctx, chunks, peak_flops,
+  hbm_bw)``, each of the whole model (all its heads and layers).
+
+The chips are the configuration's: ``serve.tp`` chips share each layer,
+and a cell's ``chips`` must equal it. The program puts every engine on
+the first ``tp`` devices (``launch/mesh.py``), so ``serve.engines``
+engines are replicas sharing those chips; engines on chips of their own
+are not served yet. The readers get the cell's chip count as
+``ctx["chips"]``.
 """
 from __future__ import annotations
 
@@ -28,14 +58,10 @@ ROOT = HERE.parents[1]
 sys.path.insert(0, str(ROOT / "src"))
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 
-import flops  # noqa: E402
 import peaks  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 import xtrace  # noqa: E402
-from reference import Reference  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -68,38 +94,56 @@ def load_cell(bench: dict, name: str):
     return cell, config, mix, limits
 
 
-def model_dims(config: dict) -> dict:
-    """The sizes the benchmark's arithmetic and reference use, from the
-    configuration file's published keys."""
-    c = config
-    heads = c["num_attention_heads"]
-    return {
-        "num_layers": c["num_hidden_layers"],
-        "d_model": c["hidden_size"],
-        "num_heads": heads,
-        "num_kv_heads": c["num_key_value_heads"],
-        "head_dim": c.get("head_dim") or c["hidden_size"] // heads,
-        "d_ff": c["intermediate_size"],
-        "vocab_size": c["vocab_size"],
-        "qkv_bias": bool(c.get("attention_bias",
-                               c.get("model_type") == "qwen2")),
-        "rope_theta": float(c["rope_theta"]),
-        "norm_eps": float(c["rms_norm_eps"]),
-        "tie_embeddings": bool(c["tie_word_embeddings"]),
-        "dtype": c["serve"]["dtype"],
-    }
+def load_architecture(name: str, owner: str = "?"):
+    """The architecture module ``arch/<name>.py`` (see above), loaded
+    once per process. ``owner`` names the configuration in the refusal."""
+    path = HERE / "arch" / f"{name}.py"
+    if not (isinstance(name, str) and name.isidentifier()
+            and path.is_file()):
+        known = sorted(p.stem for p in (HERE / "arch").glob("*.py"))
+        raise SystemExit(f"configuration {owner!r}: unknown architecture "
+                         f"{name!r}; known: {known}")
+    modname = f"arch_{name}"
+    if modname not in sys.modules:
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[modname] = mod
+    return sys.modules[modname]
 
 
-def program_config(name: str, m: dict, max_position: int):
-    from repro.models.common import ModelConfig
-    return ModelConfig(
-        name=name, family="dense", num_layers=m["num_layers"],
-        d_model=m["d_model"], num_heads=m["num_heads"],
-        num_kv_heads=m["num_kv_heads"], d_ff=m["d_ff"],
-        vocab_size=m["vocab_size"], head_dim=m["head_dim"],
-        qkv_bias=m["qkv_bias"], rope_theta=m["rope_theta"],
-        norm_eps=m["norm_eps"], tie_embeddings=m["tie_embeddings"],
-        max_position=max_position, dtype=jnp.dtype(m["dtype"]))
+def architecture(cell: dict, config: dict):
+    """The configuration's architecture module, after checking that the
+    configuration names one and that the cell asks for the chips the
+    configuration uses."""
+    owner = config.get("name")
+    if "architecture" not in config:
+        raise SystemExit(f"configuration {owner!r} names no architecture "
+                         f"(its 'architecture' key)")
+    srv = config["serve"]
+    if "tp" not in srv:
+        raise SystemExit(f"configuration {owner!r} gives no serve.tp")
+    if cell["chips"] != srv["tp"]:
+        raise SystemExit(
+            f"workload {cell['name']!r} asks for {cell['chips']} chips; its "
+            f"configuration's engines share serve.tp = {srv['tp']}")
+    return load_architecture(config["architecture"], owner)
+
+
+def make_params(arch, m: dict, seed: int, tp: int):
+    """The weights, at ``tp`` > 1 made straight into the engine's serving
+    shardings over its mesh, so that no device ever holds more than its
+    share and the engine's own placement moves nothing."""
+    if tp == 1:
+        return arch.make_params(m, seed)
+    from jax.sharding import NamedSharding
+    from repro.launch.mesh import make_tp_mesh
+    from repro.launch.shardings import serving_param_spec_tree
+    mesh = make_tp_mesh(tp)
+    specs = serving_param_spec_tree(
+        jax.eval_shape(lambda: arch.make_params(m, seed)), tp)
+    return arch.make_params(m, seed, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs))
 
 
 def load_metric_readers(bench: dict, cell_name: str) -> Dict[str, Callable]:
@@ -253,7 +297,7 @@ def _programs(eng) -> dict:
                                       and hasattr(v, "trace"))}
 
 
-def build_server(config: dict, m: dict, params, model, rec: Recorder,
+def build_server(config: dict, params, model, rec: Recorder,
                  programs: Optional[List[dict]] = None):
     """The cell's server, from ``launch/serve.build_server``. Engine i
     takes ``programs[i]`` (another server's engine i's compiled-program
@@ -264,7 +308,6 @@ def build_server(config: dict, m: dict, params, model, rec: Recorder,
     from repro.serving.server import ServerConfig
 
     srv_opts = config["serve"]
-    cfg = program_config(config["name"], m, srv_opts["max_seq"])
     # the router's tie-breaks are the deployment's, not the run's: one
     # fixed seed, so every run routes its requests alike
     sc = ServerConfig(policy=srv_opts["policy"], seed=0)
@@ -279,12 +322,13 @@ def build_server(config: dict, m: dict, params, model, rec: Recorder,
                    block_size=srv_opts["block_size"],
                    kv_dtype=sc.kv_dtype, host_kv_budget=sc.host_kv_budget,
                    preemption=sc.preemption,
-                   slo_time_scale=sc.slo_time_scale)
+                   slo_time_scale=sc.slo_time_scale, tp=srv_opts["tp"])
         if programs is not None:
             vars(e).update(programs[i])
         return e
 
-    srv = _build(cfg, sc, engines=srv_opts["engines"],
+    srv = _build(model.cfg, sc, engines=srv_opts["engines"],
+                 tp=srv_opts["tp"],
                  max_seq=srv_opts["max_seq"],
                  max_slots=srv_opts["max_slots"], params=params,
                  engine_factory=engine, on_token=rec.on_token)
@@ -426,15 +470,16 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     else:
         pk = {"bf16_flops": 1.0, "hbm_bytes_per_s": 1.0}
     dev = jax.devices()[0]
-    m = model_dims(config)
+    arch = architecture(cell, config)
+    m = arch.dims(config)
     rec = Recorder()
     lead = int(mix["lead_in_steps"])
 
-    params = weights.make_params(m, seed)
-    model = build_model(program_config(config["name"], m,
-                                       config["serve"]["max_seq"]))
+    params = make_params(arch, m, seed, config["serve"]["tp"])
+    model = build_model(arch.program_config(config["name"], m,
+                                            config["serve"]["max_seq"]))
     clients = int(mix["concurrency"])
-    warm = build_server(config, m, params, model, rec)
+    warm = build_server(config, params, model, rec)
     _warm_up(Clients(warm, traffic.build(mix, mix["warmup_seed"],
                                          m["vocab_size"]), clients),
              lead, seconds, rec, log)
@@ -444,7 +489,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     rec.reset()
 
-    srv = build_server(config, m, params, model, rec, programs)
+    srv = build_server(config, params, model, rec, programs)
     load = Clients(srv, traffic.build(mix, seed, m["vocab_size"]), clients)
     for _ in range(lead):
         load.step()
@@ -513,7 +558,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         "m": m, "peaks": pk, "seconds": t_close - t_open, "t_open": t_open,
         "t_close": t_close, "rec": rec, "window_compiles": window_compiles,
         "handovers": handovers, "finished_in_window": fin_close - fin0,
-        "trace": None, "flops": flops,
+        "trace": None, "flops": arch, "chips": cell["chips"],
     }
     breakdown = None
     if trace:
@@ -546,7 +591,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     del srv, load, params, programs, model
     gc.collect()
     t_ref = time.perf_counter()
-    ref = Reference(m, seed)
+    ref = arch.Reference(m, seed)
     gap, ctl_gap, n_tok = [], [], 0
     for r in sample:
         gen = np.asarray(r.generated, np.int32)

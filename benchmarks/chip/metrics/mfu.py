@@ -1,7 +1,9 @@
 """Model FLOPs of the work the engine steps of the window did (prompt
 tokens prefilled, tokens decoded, each over its actual context; the LM
 head for every decoded token and once per completed prompt) over the
-window's length times the chip's bf16 peak, in %. Layer: model step.
+window's length times the bf16 peak of the cell's chips (``chips`` x
+one chip's), in %: the share of each chip's peak where the chips share
+the work evenly, as tensor parallelism shares it. Layer: model step.
 Moves ``tokens_per_s``."""
 
 
@@ -17,4 +19,5 @@ def read(ctx):
                      for c0, cl, done in s.chunks)
     if not total:
         return None
-    return 100.0 * total / ((t1 - t0) * ctx["peaks"]["bf16_flops"])
+    peak = ctx["chips"] * ctx["peaks"]["bf16_flops"]
+    return 100.0 * total / ((t1 - t0) * peak)

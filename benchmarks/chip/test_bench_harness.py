@@ -10,6 +10,7 @@ import sys
 import time
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
@@ -18,12 +19,14 @@ import harness
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 
-TINY = {"name": "tiny", "model_type": "qwen2", "hidden_size": 64,
+TINY = {"name": "tiny", "architecture": "dense_gqa", "model_type": "qwen2",
+        "hidden_size": 64,
         "intermediate_size": 96, "num_attention_heads": 4,
         "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 256,
         "max_position_embeddings": 128, "rms_norm_eps": 1e-5,
         "rope_theta": 10000.0, "tie_word_embeddings": False,
-        "serve": {"dtype": "bfloat16", "policy": "cascade", "engines": 1,
+        "serve": {"dtype": "bfloat16", "policy": "cascade", "tp": 1,
+                  "engines": 1,
                   "max_slots": 4, "max_seq": 128, "block_size": 16,
                   "token_budget": 512}}
 MIX = {"requests": 400, "concurrency": 4,
@@ -40,12 +43,13 @@ LIMITS = {"logit_gap": 0.05, "min_sampled_tokens": 16}
 CELL = {"name": "tiny.chat", "config": "tiny", "traffic": "chat", "chips": 1}
 
 
-def _run(seed=3, control=False, trace=False, seconds=3.0, log=None):
+def _run(seed=3, control=False, trace=False, seconds=3.0, log=None,
+         cell=CELL, config=TINY):
     bench = {"end_to_end": [], "per_layer": [
         {"name": "window_compiles", "unit": "count"}]}
     return harness.run("tiny.chat", seed, seconds, trace,
                        t_process=time.perf_counter(), require_tpu=False,
-                       bench=bench, files=(CELL, TINY, MIX, LIMITS),
+                       bench=bench, files=(cell, config, MIX, LIMITS),
                        control=control, log=log or (lambda *a, **k: None))
 
 
@@ -80,6 +84,74 @@ def test_sample_drawn_past_a_short_window():
     assert res["compared"]["sampled_tokens"]["value"] >= 16
     drain = [ln for ln in lines if ln.startswith("drain:")]
     assert drain and int(drain[0].split()[1]) > 0, lines
+
+
+def test_tensor_parallel_run_is_correct(monkeypatch):
+    # the same cell at tp = 2 over two devices: weights made in their
+    # shardings, every step through shard_map with its all-reduces
+    from repro.serving import engine
+    built, real = [], engine.Engine.__init__
+
+    def spy(self, *a, **k):
+        real(self, *a, **k)
+        built.append(self)
+
+    monkeypatch.setattr(engine.Engine, "__init__", spy)
+    tp2 = dict(TINY, serve=dict(TINY["serve"], tp=2))
+    res = _run(seed=2**31 + 21, cell=dict(CELL, chips=2), config=tp2)
+    assert res["correct"], res["compared"]
+    assert res["compared"]["sampled_tokens"]["value"] >= 16
+    # the warm-up's engine and the measured one, both at tp = 2, serve
+    # the shards the harness made: the engine's placement moved nothing
+    assert len(built) == 2 and all(e.tp == 2 for e in built)
+
+    def buffers(e):
+        return [{s.device: s.data.unsafe_buffer_pointer()
+                 for s in a.addressable_shards}
+                for a in jax.tree.leaves(e.params)]
+
+    assert buffers(built[0]) == buffers(built[1])
+
+
+def test_exchange_between_chips_left_out(monkeypatch):
+    # tp = 2 with the all-reduces after wo and w_down dropped: each chip
+    # goes on with its own partial sums
+    from repro.models import attention, mlp
+    for mod in (attention, mlp):
+        monkeypatch.setattr(mod, "psum_if_tp", lambda x, cfg: x)
+    tp2 = dict(TINY, serve=dict(TINY["serve"], tp=2))
+    res = _run(seed=2**31 + 22, cell=dict(CELL, chips=2), config=tp2)
+    assert not res["correct"]
+    assert res["compared"]["logit_gap"]["value"] > LIMITS["logit_gap"]
+
+
+@pytest.mark.parametrize("case", ["no-architecture", "unknown-architecture",
+                                  "chips-not-tp"])
+def test_configuration_refused_by_name(case):
+    config, cell = dict(TINY), dict(CELL)
+    if case == "no-architecture":
+        del config["architecture"]
+        want = "names no architecture"
+    elif case == "unknown-architecture":
+        config["architecture"] = "mamba_hybrid"
+        want = "unknown architecture 'mamba_hybrid'"
+    else:
+        cell["chips"] = 4
+        want = "asks for 4 chips"
+    with pytest.raises(SystemExit, match=want):
+        harness.architecture(cell, config)
+    with pytest.raises(SystemExit, match=want):
+        _run(cell=cell, config=config)
+
+
+def test_architecture_loaded_by_name():
+    arch = harness.load_architecture("dense_gqa")
+    assert arch is harness.architecture(CELL, TINY)
+    for name in ("dims", "program_config", "make_params", "Reference",
+                 "token_flops", "chunk_flops", "attn_least_time"):
+        assert callable(getattr(arch, name)), name
+    with pytest.raises(SystemExit, match="unknown architecture"):
+        harness.load_architecture("../harness")
 
 
 def test_token_altered_where_produced(monkeypatch):
@@ -145,9 +217,9 @@ def test_every_name_has_its_files():
     for c in bench["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
-        harness.model_dims(cfg)
     for w in bench["workloads"]:
-        harness.load_cell(bench, w["name"])
+        cell, cfg, _, _ = harness.load_cell(bench, w["name"])
+        harness.architecture(cell, cfg).dims(cfg)
         assert harness.load_metric_readers(bench, w["name"])
     names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
     assert len(names) == len(set(names))

@@ -148,8 +148,8 @@ def served(tmp_path_factory):
     from repro.serving.request import ServeRequest
     from repro.serving.server import MILSServer, ServerConfig
 
-    m = harness.model_dims(TINY)
-    model = build_model(harness.program_config("tiny", m, 64))
+    arch = harness.load_architecture("dense_gqa")
+    model = build_model(arch.program_config("tiny", arch.dims(TINY), 64))
     params = model.init(jax.random.PRNGKey(0))
     plan = PipelinePlan([Stage(0.0, 24.0, 1),
                          Stage(24.0, float("inf"), 1)], 0.0)

@@ -57,3 +57,54 @@ def test_seams_are_not_labelled_gaps():
                       [("server.step", 0, 2 * MS, {})], 1)
     gaps = dict(xtrace.reduce(tr, (0, 2 * MS))["idle_gaps"])
     assert set(gaps) == {"(seams between ops)"}
+
+
+def _collective_share():
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "metric_collective_share",
+        Path(__file__).parent / "metrics" / "collective_share.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_collective_share_two_chips():
+    read = _collective_share().read
+    # chip 0: busy 0-8 ms, of it an all-reduce 2-3 ms nested in a fusion
+    # and an async start/done pair 5-6 ms: 2 of 8 ms. Chip 1: busy 0-4 ms,
+    # an all-gather 1-2 ms: 1 of 4 ms. An op outside the traced server
+    # steps, and one that only consumes an all-reduce's result, count for
+    # nothing.
+    ops = [("fusion.1", 0, 4 * MS, 0),
+           ("all-reduce.7", 2 * MS, 3 * MS, 0),
+           ("fusion.2 = bf16[8,5120] fusion(all-reduce.7)", 4 * MS, 5 * MS,
+            0),
+           ("all-reduce-start.3", 5 * MS, 5.5 * MS, 0),
+           ("all-reduce-done.3", 5.5 * MS, 6 * MS, 0),
+           ("fusion.3", 6 * MS, 8 * MS, 0),
+           ("all-reduce.9", 11 * MS, 12 * MS, 0),
+           ("fusion.1", 0, 4 * MS, 1),
+           ("all-gather.2", 1 * MS, 2 * MS, 1)]
+    spans = [("server.step", 0, 5 * MS, {}), ("server.step", 5 * MS, 10 * MS, {})]
+    got = read({"trace": xtrace.Trace(ops, spans, 2)})
+    assert got == pytest.approx(100.0 * (2 / 8 + 1 / 4) / 2)
+
+
+def test_collective_share_reads_nothing_without_collectives():
+    read = _collective_share().read
+    one_chip = xtrace.Trace([("paged_mixed_attention.3", 0, 2 * MS, 0)],
+                            [("server.step", 0, 3 * MS, {})], 1)
+    assert read({"trace": one_chip}) is None
+    assert read({"trace": None}) is None
+
+
+@pytest.mark.parametrize("name,want", [
+    ("all-reduce.12", True), ("all-reduce-start.1", True),
+    ("all-gather-done.4", True), ("reduce-scatter.2", True),
+    ("collective-permute-start", True), ("%all-to-all.1", True),
+    ("fusion.2 = bf16[1,5120] fusion(all-reduce.1)", False),
+    ("paged_mixed_attention.11", False), ("reduce.5", False)])
+def test_collective_names(name, want):
+    assert _collective_share().is_collective(name) is want
