@@ -1,4 +1,4 @@
-"""qwen2.5-14b — dense GQA with QKV bias. [hf:Qwen/Qwen2.5-0.5B]"""
+"""qwen2.5-14b — dense GQA with QKV bias. [hf:Qwen/Qwen2.5-14B]"""
 from repro.models.common import ModelConfig
 
 
@@ -14,5 +14,5 @@ def config() -> ModelConfig:
         vocab_size=152064,
         qkv_bias=True,
         rope_theta=1_000_000.0,
-        source="[hf:Qwen/Qwen2.5-0.5B]",
+        source="[hf:Qwen/Qwen2.5-14B]",
     )

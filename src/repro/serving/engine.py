@@ -221,21 +221,23 @@ class Engine:
             # so the fixed-shape device loop cannot corrupt live blocks
             self.garbage_block = self.num_blocks
             self.kv_dtype = kv_dtype
-            if kv_dtype == "int8":
-                # int8 pools halve KV bytes, so the same token_budget holds
-                # nearly 2x the blocks (DESIGN.md §Quantized KV blocks);
-                # quantized rows are only readable by the fused kernel and
-                # the dense gather
-                self.cache = model.init_paged_cache(self.num_blocks + 1,
-                                                    block_size,
-                                                    kv_dtype=kv_dtype)
-            else:
-                self.cache = model.init_paged_cache(self.num_blocks + 1,
-                                                    block_size)
+            # int8 pools halve KV bytes, so the same token_budget holds
+            # nearly 2x the blocks (DESIGN.md §Quantized KV blocks);
+            # quantized rows are only readable by the fused kernel and the
+            # dense gather
+            init = functools.partial(
+                model.init_paged_cache, self.num_blocks + 1, block_size,
+                **({"kv_dtype": kv_dtype} if kv_dtype == "int8" else {}))
             if self.tp > 1:
-                self._pool_spec = pool_spec_tree(self.cache)
-                self.cache = jax.device_put(self.cache, jax.tree.map(
-                    lambda s: NamedSharding(self.mesh, s), self._pool_spec))
+                # made in its sharding: no device ever holds more than its
+                # Hkv/tp heads of the pool (the whole pool is tp x what
+                # one device can spare beside its weights)
+                self._pool_spec = pool_spec_tree(jax.eval_shape(init))
+                self.cache = jax.jit(init, out_shardings=jax.tree.map(
+                    lambda s: NamedSharding(self.mesh, s),
+                    self._pool_spec))()
+            else:
+                self.cache = init()
             self.block_tables: List[List[int]] = [[] for _ in range(max_slots)]
             self._bytes_per_block = kv_bytes(self.cache) / (self.num_blocks + 1)
             self.device_resident = (device_resident
@@ -388,6 +390,10 @@ class Engine:
         # KV block
         self.work_items = 0
         self.real_work_items = 0
+        # operand bytes, on each chip, of the collectives in the launched
+        # programs (the ``engine.launch`` span's ``collective_bytes``,
+        # summed; 0 at tp = 1, see ``_collective_bytes``)
+        self.collective_bytes = 0
         # first admissions of requests a server submitted, and the sum of
         # their queue waits (host clock, submit to admission)
         self.admitted_total = 0
@@ -422,6 +428,24 @@ class Engine:
                 lambda s: NamedSharding(self.mesh, s),
                 piece_spec_tree(piece)))
         return jax.tree.map(jnp.asarray, host)
+
+    def _collective_bytes(self, rows: int, sampled: int) -> Optional[int]:
+        """Operand bytes, on each chip, of the collectives in a program
+        that runs ``rows`` token rows (padding included) through the stack
+        and gives logits for ``sampled`` of them: every row passes the
+        embedding's psum and, in every layer, the psums after ``wo`` and
+        ``w_down``, each over ``d_model``; every sampled row passes the
+        logits all-gather, whose operand is the chip's ``vocab / tp``
+        columns. 0 at tp = 1. ``None`` where the layers carry experts,
+        whose FFN psum has another shape, so nothing is counted."""
+        if self.tp == 1:
+            return 0
+        cfg = self.model.cfg
+        if cfg.num_experts:
+            return None
+        width = jnp.dtype(cfg.dtype).itemsize
+        return width * ((2 * cfg.num_layers + 1) * rows * cfg.d_model
+                        + sampled * (cfg.vocab_size // self.tp))
 
     # ---- drain-time leak check (DESIGN.md §Fault tolerance) ---------------
     def check_drained(self, strict: bool = True) -> None:
@@ -1498,11 +1522,19 @@ class Engine:
                 # their common table width
                 self.work_items += real
                 self.real_work_items += real
+                # the whole decode batch and every chunk row run through
+                # the stack; each chunk samples at its last row
+                nck, ck_len = ck_toks.shape
+                coll = self._collective_bytes(
+                    self.max_slots + nck * ck_len, self.max_slots + nck)
+                self.collective_bytes += coll or 0
                 if _span.is_enabled():
-                    sp.set_metadata(
-                        horizon=h, items=real, real_items=real,
-                        capacity=(self.max_slots + len(chunk_plan))
-                        * max(self._nbt_cap, bt_ck.shape[1]))
+                    stats = dict(horizon=h, items=real, real_items=real,
+                                 capacity=(self.max_slots + len(chunk_plan))
+                                 * max(self._nbt_cap, bt_ck.shape[1]))
+                    if coll is not None:
+                        stats["collective_bytes"] = coll
+                    sp.set_metadata(**stats)
                 (self.cache, self._dev_tok, self._dev_len, new_tok,
                  ck_tok) = attn_call(self._mixed, self.params, self.cache,
                                      self._dev_bt, self._dev_tok,
@@ -1560,11 +1592,17 @@ class Engine:
                     blocks_for(n + h - 1, self.block_size) for n in lens))
                 self.work_items += items
                 self.real_work_items += real
+                # every iteration runs and samples the whole batch
+                coll = self._collective_bytes(h * self.max_slots,
+                                              h * self.max_slots)
+                self.collective_bytes += coll or 0
                 if _span.is_enabled():
-                    sp.set_metadata(
-                        horizon=h, items=items, real_items=real,
-                        capacity=(self.max_slots * self._nbt_cap * h
-                                  if fused else items))
+                    stats = dict(horizon=h, items=items, real_items=real,
+                                 capacity=(self.max_slots * self._nbt_cap * h
+                                           if fused else items))
+                    if coll is not None:
+                        stats["collective_bytes"] = coll
+                    sp.set_metadata(**stats)
                 # num_work only shapes the flat grid's static work list;
                 # for the other backends key the jit cache on a single
                 # value so growth of the live block count never forces a

@@ -103,6 +103,36 @@ def test_tp2_doubles_resident_kv_at_equal_per_device_budget(setup):
     assert shard.size == leaf2.size // 2                 # per-device half
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_tp2_pool_made_in_its_sharding(setup, monkeypatch, kv_dtype):
+    """At tp > 1 the pool is never made whole on one device and then
+    split (the whole pool is tp x what a device can spare beside its
+    weights): the model's pool constructor runs only under trace, inside
+    the program whose outputs carry the pool's sharding."""
+    cfg, model, params = setup
+    from repro.models import transformer
+    made = []
+    real = transformer.init_paged_cache
+
+    def init(*a, **kw):
+        pool = real(*a, **kw)
+        made.append(isinstance(jax.tree.leaves(pool)[0], jax.core.Tracer))
+        return pool
+
+    monkeypatch.setattr(transformer, "init_paged_cache", init)
+    eng = _engine(model, params, 2, kv_dtype=kv_dtype,
+                  attn_backend="fused")
+    assert made and all(made)
+    for leaf in jax.tree.leaves(eng.cache):
+        assert leaf.sharding.spec[2] == "model"
+        for shard in leaf.addressable_shards:
+            assert shard.data.shape[2] == cfg.num_kv_heads // 2
+    # at tp = 1 the pool is made as before, eagerly on the one device
+    made.clear()
+    _engine(model, params, 1, kv_dtype=kv_dtype, attn_backend="fused")
+    assert made == [False]
+
+
 def test_tp2_one_attn_call_one_d2h_per_mixed_step(setup, monkeypatch):
     """The fused one-launch and one-sync contracts hold under shard_map:
     a tp=2 mixed step (long prompt chunking beside live decodes) makes
@@ -297,3 +327,113 @@ def test_build_server_full_stack_tp2(setup):
     srv.run(reqs, max_steps=400)
     assert len(srv.finished) == len(reqs)
     assert all(len(r.generated) == r.max_new_tokens for r in srv.finished)
+
+
+# --------------------------------------------------------------------------
+# The collectives' byte counter (``engine.launch``'s ``collective_bytes``)
+# --------------------------------------------------------------------------
+def _record_launches(monkeypatch):
+    """Each ``engine.launch`` span's stats (a stand-in span) and each
+    program launched, with its arguments."""
+    launches, programs = [], []
+
+    class Span:
+        def __init__(self, name, **stats):
+            self.name, self.stats = name, dict(stats)
+
+        def __enter__(self):
+            if self.name == "engine.launch":
+                launches.append(self.stats)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **stats):
+            self.stats.update(stats)
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+    real = engine_mod.attn_call
+
+    def call(fn, *args, **kw):
+        programs.append((fn, args))
+        return real(fn, *args, **kw)
+
+    monkeypatch.setattr(engine_mod, "_span", Span)
+    monkeypatch.setattr(engine_mod, "attn_call", call)
+    return launches, programs
+
+
+def _jaxpr_collective_bytes(fn, args) -> int:
+    """Operand bytes of the psums and all-gathers in ``fn``'s jaxpr, each
+    counted as often as the scans around it run it."""
+    def walk(jaxpr, times):
+        total = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("psum", "all_gather"):
+                total += times * sum(v.aval.size * v.aval.dtype.itemsize
+                                     for v in eqn.invars)
+            inner = times * eqn.params.get("length", 1)
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        total += walk(sub, inner)
+        return total
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr, 1)
+
+
+def _serve_mixed_then_decode(cfg, eng):
+    reqs = _mkreqs(cfg.vocab_size, [(9, 6), (21, 6)], seed=7)
+    for r in reqs:
+        eng.submit(r)
+    while not all(r.done for r in reqs):
+        eng.step(burst=4)
+
+
+def test_collective_bytes_zero_at_tp1(setup, monkeypatch):
+    cfg, model, params = setup
+    launches, _ = _record_launches(monkeypatch)
+    eng = _engine(model, params, 1, attn_backend="fused",
+                  prefill_token_budget=8)
+    _serve_mixed_then_decode(cfg, eng)
+    assert {s["kind"] for s in launches} == {"mixed", "burst"}
+    assert all(s["collective_bytes"] == 0 for s in launches)
+    assert eng.collective_bytes == 0
+
+
+@pytest.mark.parametrize("kind", ["burst", "mixed"])
+def test_collective_bytes_pinned_to_step_shapes(setup, monkeypatch, kind):
+    """At tp = 2 a decode-only step (a burst of ``horizon`` iterations over
+    the whole batch) and a mixed step (the whole batch beside the chunk
+    rows) each count exactly the operand bytes of the embedding psum, two
+    psums a layer over ``d_model`` and the logits all-gather over the
+    chip's ``vocab / 2`` columns, as their program's jaxpr holds them."""
+    cfg, model, params = setup
+    launches, programs = _record_launches(monkeypatch)
+    eng = _engine(model, params, 2, attn_backend="fused",
+                  prefill_token_budget=8)
+    _serve_mixed_then_decode(cfg, eng)
+    assert len(launches) == len(programs)
+    assert eng.collective_bytes == sum(s["collective_bytes"]
+                                       for s in launches)
+    # the longest burst, and the first mixed step
+    i = max((i for i, s in enumerate(launches) if s["kind"] == kind),
+            key=lambda i: (launches[i]["horizon"], -i))
+    stats, (fn, args) = launches[i], programs[i]
+    L, D, V, S = cfg.num_layers, cfg.d_model, cfg.vocab_size, eng.max_slots
+    width = np.dtype(cfg.dtype).itemsize
+    if kind == "burst":
+        h = stats["horizon"]
+        assert h > 1
+        rows = sampled = h * S
+    else:
+        nck, clen = args[5].shape            # the step's chunk tokens
+        rows, sampled = S + nck * clen, S + nck
+    want = width * ((2 * L + 1) * rows * D + sampled * V // 2)
+    assert stats["collective_bytes"] == want
+    assert _jaxpr_collective_bytes(fn, args) == want
